@@ -106,8 +106,7 @@ def fig8_partitioned_join(n_fact: int = 1 << 21):
             .measure("lo_revenue").group_by(64).build())
     # measure (or load) this backend's bandwidths + launch overhead; the
     # execute path's part_bits sizing reads the same calibration cache
-    hw = CAL.calibrated_hardware(SM.TPU_V5E if jax.default_backend() ==
-                                 "tpu" else SM.HOST)
+    hw = CAL.calibrated_hardware(SM.base_hardware())
     strategies = ("fused", "opat", "part", "part_loop")
     for log_dim in (12, 16, 20, 22):
         db = _fig8_db(n_fact, 1 << log_dim)
@@ -972,10 +971,13 @@ def write_json(out_dir: str, name: str, rows) -> None:
     # recorded on an 8-virtual-device CI host and a 1-device laptop must
     # be tellable apart before anyone compares their timings
     dc = jax.device_count()
+    dev = jax.devices()[0]
     payload = {
         "table": name,
         "unix_time": time.time(),
         "backend": jax.default_backend(),
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "device_count": dc,
         "rows": [dict({"name": n, "us_per_call": us, "derived": d},
                       extra=dict(extra or {}, device_count=dc))
@@ -987,6 +989,12 @@ def write_json(out_dir: str, name: str, rows) -> None:
 
 
 def main() -> None:
+    # JAX_COMPILATION_CACHE_DIR, when set, places the compile cache; else
+    # a fixed directory in the checkout, so repeated runs hit it
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache"))
     argv = sys.argv[1:]
     json_out = "bench_out"      # every table records its trajectory
     if "--json" in argv:

@@ -1,12 +1,13 @@
 """Public jit'd entry points for the Crystal kernels.
 
-Each op dispatches between the Pallas kernel (TPU target; interpret=True on
-CPU) and the pure-jnp reference path.  The SQL engine (repro/sql) calls
+Each op dispatches between its Pallas kernel and its jnp path, which XLA
+compiles for whatever backend runs it.  The SQL engine (repro/sql) calls
 these; ``mode`` is usually left as "auto":
 
-  auto   -> jnp path on CPU (fast host execution), kernels on TPU
-  kernel -> force Pallas (interpret on CPU) — what the tests exercise
-  ref    -> force the jnp oracle
+  auto   -> the jnp path, except on a TPU for the ops in ``TPU_KERNELS``
+  kernel -> force Pallas (compiled on a TPU, interpreted elsewhere) —
+            what the kernel tests exercise and what A/B runs compare
+  ref    -> force the jnp path
 """
 from __future__ import annotations
 
@@ -23,19 +24,38 @@ from repro.kernels import radix_part as _radix
 from repro.kernels import ref as _ref
 from repro.kernels import select_scan as _sel
 from repro.kernels import unpack as _unp
-from repro.kernels.common import DEFAULT_TILE, decode_words, gather_decode
+from repro.kernels.common import DEFAULT_TILE, gather_decode
+
+#: The op families that dispatch here (the ``op`` names below).
+OPS = ("select_scan", "unpack", "project", "build_hash_table", "probe_agg",
+       "probe_join", "part_probe", "radix_sort", "radix_partition",
+       "reduce_sum", "group_sum", "multi_spja", "spja")
+
+#: Ops whose Pallas kernel ``mode="auto"`` runs on a TPU.  An op joins
+#: only once its kernel compiles for the chip and a chip run shows it
+#: beating the op's XLA path; until then the chip runs the jnp path.
+#: Empty: no kernel has met that bar yet (several do not lower at all —
+#: scatter-add, cumsum and ``pl.ANY`` loads are refused by the TPU
+#: compiler).
+TPU_KERNELS: frozenset = frozenset()
 
 
-def _use_kernel(mode: str) -> bool:
+def use_kernel(op: str, mode: str) -> bool:
+    """Whether ``op`` runs its Pallas kernel under ``mode``."""
     if mode == "kernel":
         return True
     if mode == "ref":
         return False
-    return jax.default_backend() == "tpu"
+    return op in TPU_KERNELS and jax.default_backend() == "tpu"
+
+
+def impl(op: str, mode: str) -> str:
+    """``"pallas"`` or ``"xla"``: the implementation ``op`` runs."""
+    return "pallas" if use_kernel(op, mode) else "xla"
 
 
 def select_scan(x, y, lo, hi, mode: str = "auto", tile: int = DEFAULT_TILE):
-    if _use_kernel(mode):
+    if use_kernel("select_scan", mode):
         out, cnt = _sel.select_scan(x, y, lo, hi, tile=tile)
         return out[:x.shape[0]], cnt
     return _ref.select_scan(x, y, lo, hi)
@@ -58,14 +78,14 @@ def unpack(words, n: int, phys: int, ref=0, mode: str = "auto",
     primitive (host paths, tests, the in-register decode's oracle)."""
     if phys == 32:
         return words[:n] + jnp.int32(ref)
-    if _use_kernel(mode):
+    if use_kernel("unpack", mode):
         return _unp.unpack(words, jnp.int32(ref), phys, tile=tile)[:n]
     return _unpack_ref_jit(words, n, phys, jnp.int32(ref))
 
 
 @functools.partial(jax.jit, static_argnames=("phys",))
 def _select_packed_ref_jit(words, y, lo, hi, *, phys):
-    x = decode_words(words, phys)[:y.shape[0]]
+    x = _decode_stream(words, phys, 0, y.shape[0])
     return _ref.select_scan(x, y, lo, hi)
 
 
@@ -77,7 +97,7 @@ def select_scan_packed(words, y, lo, hi, phys: int, mode: str = "auto",
     filtering needs no reference correction at all."""
     if phys == 32:
         return select_scan(words, y, lo, hi, mode=mode, tile=tile)
-    if _use_kernel(mode):
+    if use_kernel("select_scan", mode):
         out, cnt = _sel.select_scan_packed(words, y, lo, hi, phys,
                                            tile=tile)
         return out[:y.shape[0]], cnt
@@ -85,38 +105,42 @@ def select_scan_packed(words, y, lo, hi, phys: int, mode: str = "auto",
 
 
 def _decode_stream(arr, width: int, ref, n: int):
-    """Ref-path stream normalizer: identity for plain streams, in-trace
-    decode (fused by XLA with the consuming scan, never materialized
-    between ops) for packed ones."""
+    """XLA-path stream normalizer: identity for plain streams, in-trace
+    decode of the first ``n`` values (fused by XLA with the consuming
+    scan, never materialized between ops) for packed ones.  Positional
+    (``gather_decode`` of ``0..n-1``), not ``decode_words``: the TPU
+    compiler lays the latter's ``(n_words, 32 // width)`` intermediate
+    out per width, and streams of mixed widths then relayout through
+    padded temporaries (GBs per 4M-row block, minutes of compile)."""
     if width == 32:
         return arr
-    return decode_words(arr, width, ref)[:n]
+    return gather_decode(arr, jnp.arange(n, dtype=jnp.int32), width, ref)
 
 
 def project(x1, x2, a, b, sigmoid=False, mode: str = "auto",
             tile: int = DEFAULT_TILE):
-    if _use_kernel(mode):
+    if use_kernel("project", mode):
         return _proj.project(x1, x2, a, b, sigmoid=sigmoid, tile=tile)
     return _ref.project(x1, x2, a, b, sigmoid=sigmoid)
 
 
 def build_hash_table(keys, vals, n_slots, mode: str = "auto",
                      tile: int = DEFAULT_TILE):
-    if _use_kernel(mode):
+    if use_kernel("build_hash_table", mode):
         return _hj.build(keys, vals, n_slots, tile=tile)
     return _ref.build(keys, vals, n_slots)
 
 
 def probe_agg(keys, vals, ht_keys, ht_vals, mode: str = "auto",
               tile: int = DEFAULT_TILE):
-    if _use_kernel(mode):
+    if use_kernel("probe_agg", mode):
         return _hj.probe_agg(keys, vals, ht_keys, ht_vals, tile=tile)
     return _ref.probe_agg(keys, vals, ht_keys, ht_vals)
 
 
 def probe_join(keys, vals, ht_keys, ht_vals, mode: str = "auto",
                tile: int = DEFAULT_TILE):
-    if _use_kernel(mode):
+    if use_kernel("probe_join", mode):
         outp, outv, cnt = _hj.probe_join(keys, vals, ht_keys, ht_vals,
                                          tile=tile)
         return outp[:keys.shape[0]], outv[:keys.shape[0]], cnt
@@ -149,7 +173,7 @@ def part_probe(keys, rowids, groups, offs, counts, htk, htv, mult,
     rowids = jnp.pad(rowids, (0, n_pad - n), constant_values=-1)
     groups = jnp.pad(groups, (0, n_pad - n))
     mult = jnp.asarray(mult, jnp.int32)
-    if _use_kernel(mode):
+    if use_kernel("part_probe", mode):
         outr, outg, cnt = _pp.part_probe(keys, rowids, groups, offs,
                                          counts, htk, htv, mult, tile=tile)
         return outr, outg, cnt
@@ -186,14 +210,14 @@ def _lsb_partition_multi(keys, vals, bits: int, digit: int = 1):
         d = min(max(digit, 1), bits - s)
         if d == 1:
             bit = (comb >> (_LSB_IDX_BITS + s)) & 1
-            c0 = jnp.cumsum(1 - bit)
+            c0 = _ref.prefix_sum(1 - bit)
             pos = jnp.where(bit == 0, c0 - 1, c0[-1] + iota - c0)
         else:
             dig = (comb >> (_LSB_IDX_BITS + s)) & ((1 << d) - 1)
             pos = jnp.zeros(n, jnp.int32)
             base = jnp.int32(0)
             for b in range(1 << d):
-                c = jnp.cumsum((dig == b).astype(jnp.int32))
+                c = _ref.prefix_sum((dig == b).astype(jnp.int32))
                 pos = jnp.where(dig == b, base + c - 1, pos)
                 base = base + c[-1]
         comb = jnp.zeros_like(comb).at[pos].set(comb)
@@ -271,20 +295,20 @@ def part_join(col, rowids, groups, htk, htv, mult, bits: int,
     return _part_join_jit(col, rowids, groups, htk, htv,
                           jnp.asarray(mult, jnp.int32),
                           jnp.asarray(ref, jnp.int32), bits=bits,
-                          kernel=_use_kernel(mode), tile=tile, width=width,
-                          digit=digit)
+                          kernel=use_kernel("part_probe", mode),
+                          tile=tile, width=width, digit=digit)
 
 
 def radix_sort(keys, vals, mode: str = "auto", r: int = 8,
                tile: int = DEFAULT_TILE):
-    if _use_kernel(mode):
+    if use_kernel("radix_sort", mode):
         return _radix.radix_sort(keys, vals, r=r, tile=tile)
     return _ref.radix_sort(keys, vals)
 
 
 def radix_partition(keys, vals, start_bit, r, mode: str = "auto",
                     tile: int = DEFAULT_TILE):
-    if _use_kernel(mode):
+    if use_kernel("radix_partition", mode):
         return _radix.partition(keys, vals, start_bit, r, tile=tile)
     return _ref.partition(keys, vals, start_bit, r)
 
@@ -296,22 +320,107 @@ def radix_partition_multi(keys, vals, start_bit, r, mode: str = "auto",
     vals = tuple(vals)
     if keys.shape[0] == 0:
         return keys, vals
-    if _use_kernel(mode):
+    if use_kernel("radix_partition", mode):
         return _radix.partition_multi(keys, vals, start_bit, r, tile=tile)
     return _ref.partition_multi(keys, vals, start_bit, r)
 
 
 def reduce_sum(x, mode: str = "auto", tile: int = DEFAULT_TILE):
-    if _use_kernel(mode):
+    if use_kernel("reduce_sum", mode):
         return _agg.reduce_sum(x, tile=tile)
     return _ref.reduce_sum(x)
 
 
 def group_sum(group_ids, vals, n_groups, mode: str = "auto",
               tile: int = DEFAULT_TILE):
-    if _use_kernel(mode):
+    if use_kernel("group_sum", mode):
         return _agg.group_sum(group_ids, vals, n_groups, tile=tile)
-    return _ref.group_sum(group_ids, vals, n_groups)
+    return _group_sum_xla(group_ids, vals, n_groups)
+
+
+_group_sum_xla = jax.jit(_ref.group_sum, static_argnames=("n_groups",))
+
+
+# Row block of the XLA SPJA paths: full blocks run in a fori_loop, so one
+# block's intermediates (decoded columns, probe state, bitmaps — per
+# join, and for a wave per member) are live at a time, however long the
+# fact table is: the jnp analogue of the kernels' grid.  The TPU's XLA
+# scatter-add pads its (rows, 1) index operand to 128 lanes (~512 B per
+# row), so the block size also bounds that temporary: 2^20 rows keep it
+# near 0.5 GB.  A multiple of 32 rows, so every block starts on a word of
+# every packed width.
+XLA_BLOCK_ROWS = 1 << 20
+
+
+def _decode_planes(words, width: int, ref, rows: int):
+    """A block of ``rows`` (a multiple of 32) rows of a stream as a
+    ``(32, rows // 32)`` array: entry ``[k, i]`` is row ``32 * i + k``.
+    Every width lands in this same order (row-order-free SPJA sums do
+    not care which), lane-dense for the TPU: the block's words go
+    through one ``(rows/32, width) -> (width, rows/32)`` transpose, then
+    a shift and a mask per value lane.  Plain streams keep their
+    dtype."""
+    cols = rows // 32
+    planes = words.reshape(cols, width).T           # (words per 32 rows, .)
+    if width == 32:
+        return planes
+    per_word = 32 // width
+    shifts = (jnp.arange(per_word, dtype=jnp.int32) * width)[None, :, None]
+    vals = jax.lax.shift_right_logical(planes[:, None, :], shifts) \
+        & jnp.int32((1 << width) - 1)
+    return vals.reshape(32, cols) + ref
+
+
+def _fold_rows(streams, n_rows: int, step, acc):
+    """``acc = step(acc, cols)`` over the fact rows in blocks of
+    ``XLA_BLOCK_ROWS``: ``cols`` holds each ``(array, width, ref)``
+    stream decoded for the block's rows — full blocks as
+    :func:`_decode_planes` arrays, the rows past the last full block as
+    one static 1-D tail block — so no stream is padded or copied."""
+    n_full, tail = divmod(n_rows, XLA_BLOCK_ROWS)
+
+    def full_block(i):
+        cols = []
+        for arr, width, ref in streams:
+            n_words = XLA_BLOCK_ROWS * width // 32
+            cols.append(_decode_planes(
+                jax.lax.dynamic_slice(arr, (i * n_words,), (n_words,)),
+                width, ref, XLA_BLOCK_ROWS))
+        return cols
+
+    if n_full:
+        acc = jax.lax.fori_loop(0, n_full,
+                                lambda i, a: step(a, full_block(i)), acc)
+    if tail:
+        start = n_full * XLA_BLOCK_ROWS
+        cols = []
+        for arr, width, ref in streams:
+            per_word = 32 // width
+            w0 = start // per_word
+            cols.append(_decode_stream(arr[w0:w0 + -(-tail // per_word)],
+                                       width, ref, tail))
+        acc = step(acc, cols)
+    return acc
+
+
+def _sum_pairs(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
+def _zero_sums(shape, exact: bool):
+    """Accumulator for a ``ref.group_sums`` pair."""
+    return (jnp.zeros(shape, jnp.int32) if exact else None,
+            jnp.zeros(shape, jnp.float32))
+
+
+def _streams(cols, widths, refs):
+    return [(c, w, refs[i] if w != 32 else 0)
+            for i, (c, w) in enumerate(zip(cols, widths))]
+
+
+def _integer(arr, width: int) -> bool:
+    """Packed streams decode to int32; plain ones keep their dtype."""
+    return width != 32 or jnp.issubdtype(arr.dtype, jnp.integer)
 
 
 # one jitted executable per wave *shape* (Q, C, J, M, n_groups, n, widths):
@@ -322,22 +431,24 @@ def group_sum(group_ids, vals, n_groups, mode: str = "auto",
 @functools.partial(jax.jit,
                    static_argnames=("n_groups", "pred_widths", "key_widths",
                                     "m_widths", "n_rows"))
-def _multi_spja_ref_jit(pred_cols, pred_bounds, join_keys, key_refs,
-                        join_tables, join_mults, join_use, q_valid,
-                        measure_cols, m_refs, measure_sel, *, n_groups,
-                        pred_widths, key_widths, m_widths, n_rows):
-    pred_cols = tuple(_decode_stream(c, w, 0, n_rows)
-                      for c, w in zip(pred_cols, pred_widths))
-    join_keys = tuple(
-        _decode_stream(k, w, key_refs[j] if w != 32 else 0, n_rows)
-        for j, (k, w) in enumerate(zip(join_keys, key_widths)))
-    measure_cols = tuple(
-        (m if w == 32 else
-         _decode_stream(m, w, m_refs[i], n_rows)).astype(jnp.float32)
-        for i, (m, w) in enumerate(zip(measure_cols, m_widths)))
-    return _ref.multi_spja(pred_cols, pred_bounds, join_keys, join_tables,
-                           join_mults, join_use, q_valid, measure_cols,
-                           measure_sel, n_groups=n_groups)
+def _multi_spja_xla(pred_cols, pred_bounds, join_keys, key_refs,
+                    join_tables, join_mults, join_use, q_valid,
+                    measure_cols, m_refs, measure_sel, *, n_groups,
+                    pred_widths, key_widths, m_widths, n_rows):
+    n_p, n_j = len(pred_cols), len(join_keys)
+    streams = (_streams(pred_cols, pred_widths, [0] * n_p)
+               + _streams(join_keys, key_widths, key_refs)
+               + _streams(measure_cols, m_widths, m_refs))
+    exact = all(_integer(m, w) for m, w in zip(measure_cols, m_widths))
+
+    def step(acc, cols):
+        return _sum_pairs(acc, _ref.multi_spja_sums(
+            cols[:n_p], pred_bounds, cols[n_p:n_p + n_j], join_tables,
+            join_mults, join_use, q_valid, cols[n_p + n_j:], measure_sel,
+            n_groups=n_groups))
+
+    acc = _zero_sums((pred_bounds.shape[0], n_groups), exact)
+    return _ref.finish_sums(*_fold_rows(streams, n_rows, step, acc))
 
 
 def multi_spja(pred_cols, pred_bounds, join_keys, join_tables, join_mults,
@@ -366,7 +477,7 @@ def multi_spja(pred_cols, pred_bounds, join_keys, join_tables, join_mults,
             raise ValueError("n_rows is required when the measure stream "
                              "is bit-packed")
         n_rows = int(measure_cols[0].shape[0])
-    if _use_kernel(mode):
+    if use_kernel("multi_spja", mode):
         from repro.kernels import multi_fused
         out = multi_fused.multi_spja(
             tuple(pred_cols), pred_bounds, tuple(join_keys),
@@ -376,7 +487,7 @@ def multi_spja(pred_cols, pred_bounds, join_keys, join_tables, join_mults,
             key_refs=key_refs, m_widths=m_widths, m_refs=m_refs,
             n_rows=n_rows)
     else:
-        out = _multi_spja_ref_jit(
+        out = _multi_spja_xla(
             tuple(pred_cols), pred_bounds, tuple(join_keys), key_refs,
             tuple(join_tables), join_mults, join_use, q_valid,
             tuple(measure_cols), m_refs, measure_sel, n_groups=n_groups,
@@ -387,31 +498,32 @@ def multi_spja(pred_cols, pred_bounds, join_keys, join_tables, join_mults,
     return out
 
 
-# the whole single-query SPJA ref path under jit: eagerly, every probe's
-# while_loop iteration used to dispatch separately; one cached
-# executable per (shapes, widths, measure_op, n_groups) combination —
-# and for packed streams the in-trace decode fuses with the scan instead
-# of materializing a full-width column between ops
+# the whole single-query SPJA XLA path under jit: one cached executable
+# per (shapes, widths, measure_op, n_groups) combination, and for packed
+# streams the in-trace decode fuses with the scan instead of
+# materializing a full-width column between ops
 @functools.partial(jax.jit,
                    static_argnames=("measure_op", "n_groups", "pred_widths",
                                     "key_widths", "m_widths", "n_rows"))
-def _spja_ref_jit(pred_cols, pred_bounds, join_keys, key_refs, join_tables,
-                  group_mults, m1, m2, m_refs, *, measure_op, n_groups,
-                  pred_widths, key_widths, m_widths, n_rows):
-    pred_cols = tuple(_decode_stream(c, w, 0, n_rows)
-                      for c, w in zip(pred_cols, pred_widths))
-    join_keys = tuple(
-        _decode_stream(k, w, key_refs[j] if w != 32 else 0, n_rows)
-        for j, (k, w) in enumerate(zip(join_keys, key_widths)))
-    if m_widths[0] != 32:
-        m1 = _decode_stream(m1, m_widths[0], m_refs[0],
-                            n_rows).astype(jnp.float32)
-    if m2 is not None and m_widths[1] != 32:
-        m2 = _decode_stream(m2, m_widths[1], m_refs[1],
-                            n_rows).astype(jnp.float32)
-    return _ref.spja(pred_cols, pred_bounds, join_keys, join_tables,
-                     group_mults, m1, m2, measure_op=measure_op,
-                     n_groups=n_groups)
+def _spja_xla(pred_cols, pred_bounds, join_keys, key_refs, join_tables,
+              group_mults, m1, m2, m_refs, *, measure_op, n_groups,
+              pred_widths, key_widths, m_widths, n_rows):
+    n_p, n_j = len(pred_cols), len(join_keys)
+    ms = [m1] if m2 is None else [m1, m2]
+    streams = (_streams(pred_cols, pred_widths, [0] * n_p)
+               + _streams(join_keys, key_widths, key_refs)
+               + _streams(ms, m_widths, m_refs))
+    exact = all(_integer(m, w) for m, w in zip(ms, m_widths))
+
+    def step(acc, cols):
+        meas = cols[n_p + n_j:]
+        return _sum_pairs(acc, _ref.spja_sums(
+            cols[:n_p], pred_bounds, cols[n_p:n_p + n_j], join_tables,
+            group_mults, meas[0], meas[1] if len(meas) == 2 else None,
+            measure_op=measure_op, n_groups=n_groups))
+
+    acc = _zero_sums((n_groups,), exact)
+    return _ref.finish_sums(*_fold_rows(streams, n_rows, step, acc))
 
 
 def spja(pred_cols, pred_bounds, join_keys, join_tables, group_mults,
@@ -441,7 +553,7 @@ def spja(pred_cols, pred_bounds, join_keys, join_tables, group_mults,
             raise ValueError("n_rows is required when the measure stream "
                              "is bit-packed")
         n_rows = int(m1.shape[0])
-    if _use_kernel(mode):
+    if use_kernel("spja", mode):
         from repro.kernels import ssb_fused
         out = ssb_fused.spja(tuple(pred_cols), pred_bounds,
                              tuple(join_keys), tuple(join_tables),
@@ -452,13 +564,11 @@ def spja(pred_cols, pred_bounds, join_keys, join_tables, group_mults,
                              m_widths=m_widths, m_refs=m_refs,
                              n_rows=n_rows)
     else:
-        out = _spja_ref_jit(tuple(pred_cols), pred_bounds,
-                            tuple(join_keys), key_refs,
-                            tuple(join_tables), group_mults, m1, m2,
-                            m_refs, measure_op=measure_op,
-                            n_groups=n_groups, pred_widths=pred_widths,
-                            key_widths=key_widths, m_widths=m_widths,
-                            n_rows=n_rows)
+        out = _spja_xla(tuple(pred_cols), pred_bounds, tuple(join_keys),
+                        key_refs, tuple(join_tables), group_mults, m1, m2,
+                        m_refs, measure_op=measure_op, n_groups=n_groups,
+                        pred_widths=pred_widths, key_widths=key_widths,
+                        m_widths=m_widths, n_rows=n_rows)
     if axis_name is not None:
         out = jax.lax.psum(out, axis_name)
     return out

@@ -142,7 +142,6 @@ def _measure_interconnect(elems: int = 1 << 20) -> Optional[float]:
     interconnect to measure; the model then falls back to read_bw, which
     matches the host-loop merge actually taking that path)."""
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
     devs = jax.devices()
     if len(devs) < 2:
@@ -150,9 +149,9 @@ def _measure_interconnect(elems: int = 1 << 20) -> Optional[float]:
     d = len(devs)
     mesh = Mesh(np.array(devs), ("data",))
     x = jnp.ones((d, elems), jnp.float32)
-    f = jax.jit(shard_map(lambda y: jax.lax.psum(y, "data"), mesh=mesh,
-                          in_specs=PartitionSpec("data", None),
-                          out_specs=PartitionSpec(None, None)))
+    f = jax.jit(jax.shard_map(lambda y: jax.lax.psum(y, "data"), mesh=mesh,
+                              in_specs=PartitionSpec("data", None),
+                              out_specs=PartitionSpec(None, None)))
     t = _bench(f, x)
     return float(2.0 * (d - 1) * elems * 4 / t)
 

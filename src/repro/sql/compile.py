@@ -83,11 +83,10 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.kernels import ops
-from repro.kernels.common import DEFAULT_TILE
+from repro.kernels.common import DEFAULT_TILE, gather_decode
 from repro.sql import tune as TN
 from repro.sql import faults as FLT
 from repro.sql import hashtable as HT
@@ -139,21 +138,17 @@ def snapshot_launch_config() -> Dict[str, Dict]:
     return {k: dict(v) for k, v in LAUNCH_CONFIG.items()}
 
 
-def _tile_or_default(tile: Optional[int]) -> int:
-    """Tile for call sites with no tuned family (monolithic probe,
-    project, group_sum): explicit wins, else the shipped default."""
-    return DEFAULT_TILE if tile is None else int(tile)
-
-
-def _launch(family: str, tile: Optional[int], width: int = 32,
-            **extra) -> int:
+def _launch(family: str, tile: Optional[int], width: int = 32, *,
+            mode: str, op: Optional[str] = None, **extra) -> int:
     """Resolve + record one kernel family's launch tile.  An explicit
     ``tile=`` argument always wins (tests and A/B sweeps stay
     deterministic); ``None`` consults the tune store's winner for this
     (family, packed-width bucket) and falls back to ``DEFAULT_TILE`` on
     a cold store — byte-for-byte the pre-tuner launch.  The resolved
     configuration (with any ``extra`` knobs: radix width, partition
-    depth) lands in ``LAUNCH_CONFIG`` for result reporting."""
+    depth) lands in ``LAUNCH_CONFIG`` for result reporting, with the
+    implementation that runs under ``mode`` (``ops.impl`` of ``op``,
+    the dispatching op when it is not named like the family)."""
     if tile is not None:
         t, src = int(tile), "explicit"
     else:
@@ -164,7 +159,7 @@ def _launch(family: str, tile: Optional[int], width: int = 32,
         else:
             t, src = DEFAULT_TILE, "default"
     LAUNCH_CONFIG[family] = {"tile": t, "width": width, "source": src,
-                             **extra}
+                             "impl": ops.impl(op or family, mode), **extra}
     return t
 
 
@@ -274,10 +269,8 @@ def _measure_streams(fact, proj):
     streams = [ST.column_stream(fact, c)
                for c in ([proj.m1] if proj.op not in ("mul", "sub")
                          else [proj.m1, proj.m2])]
-    arrs = [arr if w != 32 else arr.astype(jnp.float32)
-            for arr, w, _ in streams]
-    m1 = arrs[0]
-    m2 = arrs[1] if len(arrs) == 2 else None
+    m1 = streams[0][0]
+    m2 = streams[1][0] if len(streams) == 2 else None
     widths = tuple(w for _, w, _ in streams)
     refs = jnp.asarray(np.array([r for _, _, r in streams], np.int32))
     return m1, m2, widths, refs
@@ -320,7 +313,7 @@ def _execute_fused(plan: P.Plan, db: ssb.Database, mode: str,
     FLT.maybe_fault("kernel")
     out = ops.spja(pred_cols, pred_bounds, join_keys, join_tables, mults,
                    m1, m2, measure_op=proj.op, n_groups=plan.n_groups,
-                   mode=mode, tile=_launch("spja", tile),
+                   mode=mode, tile=_launch("spja", tile, mode=mode),
                    pred_widths=pred_widths,
                    key_widths=key_widths, key_refs=key_refs,
                    m_widths=m_widths, m_refs=m_refs, n_rows=fact.n_rows)
@@ -445,7 +438,7 @@ def _execute_fused_map(plan: P.Plan, sdb, mode: str, tile: Optional[int],
     partial grids sum on the host.  A single window is byte-for-byte
     the pre-refactor whole-shard launch (memoized stacked streams)."""
     mesh = sdb.mesh
-    tile = _launch("spja", tile)    # resolve once, outside shard_fn
+    tile = _launch("spja", tile, mode=mode)  # once, outside shard_fn
     base_fact = getattr(sdb.base, sdb.fact)
     scan_cols = _fused_scan_cols(plan)
     # per-shard bytes-per-row of the scanned streams + validity mask
@@ -501,10 +494,9 @@ def _execute_fused_map(plan: P.Plan, sdb, mode: str, tile: Optional[int],
                                              w_pad) for j in joins]
             m_streams = [SH.stacked_window(sdb, c, lo, hi, w_pad)
                          for c in m_cols]
-        m_arrs = [arr if w != 32 else arr.astype(jnp.float32)
-                  for arr, w, _ in m_streams]
         sharded = {"pred": [s[0] for s in pred_streams],
-                   "key": [s[0] for s in key_streams], "m": m_arrs}
+                   "key": [s[0] for s in key_streams],
+                   "m": [s[0] for s in m_streams]}
         repl = {"pb": jnp.asarray(pb), "tables": join_tables,
                 "mults": mults,
                 "kref": jnp.asarray(np.array([s[2] for s in key_streams],
@@ -534,14 +526,14 @@ def _execute_fused_map(plan: P.Plan, sdb, mode: str, tile: Optional[int],
                        axis_name=SH.SHARD_AXIS)
         return out
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: PartitionSpec(SH.SHARD_AXIS, None),
                                first[0] if first else {}),
                   jax.tree.map(lambda _: PartitionSpec(),
                                first[1] if first else {})),
         out_specs=PartitionSpec(),
-        check_rep=False)        # Pallas calls have no replication rule
+        check_vma=False)        # Pallas calls have no replication rule
 
     report = MS.MorselReport()
     t0 = time.perf_counter()
@@ -751,8 +743,7 @@ def shared_params(plans: List[P.Plan], db: ssb.Database,
             msel[qi, 1] = mcol_ix[proj.m2]
         msel[qi, 2] = _MEASURE_OP_CODE[proj.op]
     m_streams = [ST.column_stream(fact, c) for c in mcol_ix]
-    measure_cols = [arr if w != 32 else arr.astype(jnp.float32)
-                    for arr, w, _ in m_streams]
+    measure_cols = [arr for arr, _, _ in m_streams]
     m_widths = tuple(w for _, w, _ in m_streams)
     m_refs = jnp.asarray(np.array([r for _, _, r in m_streams], np.int32))
 
@@ -820,7 +811,7 @@ def execute_shared_morsels(plans: List[P.Plan], db: ssb.Database,
     member bucket."""
     validate_wave(plans)
     reset_launch_config()
-    tile = _launch("multi_spja", tile)
+    tile = _launch("multi_spja", tile, mode=mode)
     anchor = anchor_for(plans, anchor)
     foot = list(plans) + list(anchor or [])
     col_ix, join_nodes, mcol_ix = shared_footprint(foot)
@@ -931,15 +922,29 @@ def _probe_whole(node: P.HashJoin, fact, db, rowids, group, mode, tile,
     through it."""
     htk, htv = (cache.get_or_build(db, node) if cache is not None
                 else HT.build_dim_table(db, node))
-    keys = ST.take(fact, node.fact_col, rowids)
+    col, width, ref = ST.column_stream(fact, node.fact_col)
     LAUNCH_STATS["probe"] += 1
     FLT.maybe_fault("kernel")
-    payload, sel, cnt = _probe_join_jit(
-        keys, jnp.arange(rowids.shape[0], dtype=jnp.int32),
-        htk, htv, mode=mode, tile=_tile_or_default(tile))
+    rowids, group, cnt = _probe_step(
+        col, rowids, group, htk, htv, jnp.int32(node.mult),
+        jnp.int32(ref), width=width, mode=mode,
+        tile=_launch("probe_join", tile, mode=mode))
     cnt = int(cnt)
-    sel = sel[:cnt]
-    return rowids[sel], group[sel] + payload[:cnt] * jnp.int32(node.mult)
+    return rowids[:cnt], group[:cnt]
+
+
+@functools.partial(jax.jit, static_argnames=("width", "mode", "tile"))
+def _probe_step(col, rowids, group, htk, htv, mult, ref, *, width, mode,
+                tile):
+    """One opat join as one executable (one compile per live-row count,
+    not one per eager op): gather the live rows' keys (``ST.take``'s
+    decode), probe, and gather the live columns through the selection
+    vector.  Rows past the returned count are padding."""
+    keys = gather_decode(col, rowids, width, ref)
+    payload, sel, cnt = ops.probe_join(
+        keys, jnp.arange(rowids.shape[0], dtype=jnp.int32), htk, htv,
+        mode=mode, tile=tile)
+    return rowids[sel], group[sel] + payload * mult, cnt
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "tile"))
@@ -987,7 +992,7 @@ def _probe_part_fused(node: P.HashJoin, fact, db, rowids, group, mode,
     digit = TN.tuned_digit()            # host shuffle's tuned pass width
     outr, outg, cnt = ops.part_join(
         col, rowids, group, packed.htk, packed.htv, node.mult, bits,
-        mode=mode, tile=_launch("part_probe", tile, bits=bits,
+        mode=mode, tile=_launch("part_probe", tile, mode=mode, bits=bits,
                                 digit=digit),
         width=width, ref=colref, digit=digit)
     LAUNCH_STATS["host_syncs"] += 1
@@ -1017,7 +1022,8 @@ def _probe_part_loop(node: P.HashJoin, fact, db, rowids, group, mode,
     LAUNCH_STATS["partition"] += 1
     outk, (orow, ogrp) = ops.radix_partition_multi(
         keys, (rowids, group), 0, bits,
-        mode=mode, tile=_launch("partition_multi", tile, bits=bits))
+        mode=mode, tile=_launch("partition_multi", tile, mode=mode,
+                                op="radix_partition", bits=bits))
     LAUNCH_STATS["host_syncs"] += 3
     outk_h = np.asarray(outk)
     orow_h = np.asarray(orow)
@@ -1039,7 +1045,8 @@ def _probe_part_loop(node: P.HashJoin, fact, db, rowids, group, mode,
         LAUNCH_STATS["probe"] += 1
         payload, sel, cnt = _probe_join_jit(
             jnp.asarray(pk), jnp.arange(n_pad, dtype=jnp.int32),
-            htk, htv, mode=mode, tile=_tile_or_default(tile))
+            htk, htv, mode=mode,
+            tile=_launch("probe_join", tile, mode=mode))
         LAUNCH_STATS["host_syncs"] += 3
         cnt = int(cnt)
         if cnt == 0:
@@ -1112,7 +1119,8 @@ def _execute_chain(plan: P.Plan, db: ssb.Database, mode: str,
                         words, phys, _ = ST.column_stream(fact, col)
                         out, cnt = ops.select_scan_packed(
                             words, rowids, lo2, hi2, phys, mode=mode,
-                            tile=_launch("select_scan", tile, width=phys))
+                            tile=_launch("select_scan", tile, width=phys,
+                                         mode=mode))
                         out = out[:int(cnt)]
                         group = group[out]  # identity rowids: value==pos
                         rowids = out
@@ -1125,7 +1133,7 @@ def _execute_chain(plan: P.Plan, db: ssb.Database, mode: str,
                     sel, cnt = ops.select_scan(
                         x, jnp.arange(rowids.shape[0], dtype=jnp.int32),
                         lo, hi, mode=mode,
-                        tile=_launch("select_scan", tile))
+                        tile=_launch("select_scan", tile, mode=mode))
                     sel = sel[:int(cnt)]
                     rowids = rowids[sel]
                     group = group[sel]
@@ -1141,14 +1149,12 @@ def _execute_chain(plan: P.Plan, db: ssb.Database, mode: str,
             rowids, group = join_fn(node, fact, db, rowids, group, mode,
                                     tile, cache)
         elif isinstance(node, P.Project):
-            m = ST.take(fact, node.m1, rowids).astype(jnp.float32)
+            # integer measures: group_sum sums them exactly
+            m = ST.take(fact, node.m1, rowids)
             if node.op == "mul":
-                m = m * ST.take(fact, node.m2, rowids).astype(jnp.float32)
+                m = m * ST.take(fact, node.m2, rowids)
             elif node.op == "sub":
-                m2 = ST.take(fact, node.m2, rowids).astype(jnp.float32)
-                m = m if empty else ops.project(m, m2, 1.0, -1.0,
-                                                mode=mode,
-                                                tile=_tile_or_default(tile))
+                m = m - ST.take(fact, node.m2, rowids)
             measure = m
         elif isinstance(node, P.GroupAgg):
             if partial_agg:
@@ -1160,9 +1166,9 @@ def _execute_chain(plan: P.Plan, db: ssb.Database, mode: str,
                     np.asarray(group), np.asarray(measure), node.n_groups)
             if empty:
                 return np.zeros(node.n_groups, np.float32)
-            out = ops.group_sum(group, measure, node.n_groups,
-                                mode=mode, tile=_tile_or_default(tile))
-            return np.asarray(out)
+            out = ops.group_sum(group, measure, node.n_groups, mode=mode,
+                                tile=_launch("group_sum", tile, mode=mode))
+            return np.asarray(out, np.float32)
         elif isinstance(node, P.OrderBy):
             if defer_order or empty:
                 break
@@ -1170,7 +1176,7 @@ def _execute_chain(plan: P.Plan, db: ssb.Database, mode: str,
             r = TN.tuned_r()
             _, rowids = ops.radix_sort(keys, rowids, mode=mode, r=r,
                                        tile=_launch("radix_sort", tile,
-                                                    r=r))
+                                                    mode=mode, r=r))
         else:
             raise TypeError(f"{plan.name}: cannot lower node {node!r}")
 
@@ -1263,7 +1269,8 @@ def _chain_morsels(plan: P.Plan, db: ssb.Database, mode: str,
     r = TN.tuned_r()
     _, out = ops.radix_sort(jnp.asarray(keys), jnp.asarray(rowids),
                             mode=mode, r=r,
-                            tile=_launch("radix_sort", tile, r=r))
+                            tile=_launch("radix_sort", tile, mode=mode,
+                                         r=r))
     return np.asarray(out), report
 
 

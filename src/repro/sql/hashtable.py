@@ -87,6 +87,29 @@ def filtered_build_side(db: ssb.Database, join: P.HashJoin
     return keys, vals
 
 
+# Key spans up to this many slots get a table as wide as the span.
+DIRECT_SLOTS = 1 << 22
+
+
+def table_slots(keys: np.ndarray, dim_keys: np.ndarray) -> int:
+    """Slot count of a dim hash table holding ``keys``: at most half
+    full, and — when the dimension's keys ``dim_keys`` (what fact foreign
+    keys reference) span at most ``DIRECT_SLOTS`` values — at least that
+    span.  The multiplicative hash maps any run of S consecutive
+    integers to distinct slots of a power-of-two S, so then every build
+    key sits in its home slot and a probe of any key in the span ends at
+    its first slot.  On the XLA path each probe round gathers over every
+    fact row in lock-step until the longest chain ends (up to ~20 rounds
+    at half fill on SSB's filtered build sides); a span-wide table makes
+    that one round.  Dense surrogate keys, as SSB's, always qualify."""
+    n = next_pow2(max(len(keys), 1))
+    if len(dim_keys):
+        span = int(dim_keys.max()) - int(dim_keys.min()) + 1
+        if n < span <= DIRECT_SLOTS:
+            n = 1 << (span - 1).bit_length()
+    return n
+
+
 def build_dim_table(db: ssb.Database, join: P.HashJoin
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Build the (filtered) hash table for one join's dim side.
@@ -94,7 +117,8 @@ def build_dim_table(db: ssb.Database, join: P.HashJoin
     from repro.sql import faults
     faults.maybe_fault("build")
     keys, vals = filtered_build_side(db, join)
-    n_slots = next_pow2(max(len(keys), 1))
+    n_slots = table_slots(keys, np.asarray(
+        getattr(db, join.dim)[join.key_col]))
     htk, htv = np_build(keys, vals, n_slots)
     return jnp.asarray(htk), jnp.asarray(htv)
 

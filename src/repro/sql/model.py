@@ -91,6 +91,26 @@ MAX_PART_BITS = 8                       # one 8-bit partition pass (§4.4)
 SAMPLE_STRIDE_TARGET = 1 << 16          # fact rows sampled for selectivity
 
 
+#: Hardware tables by ``jax.devices()[0].device_kind`` for TPU backends
+#: ("TPU v5 lite" is how JAX names a v5e chip).  A TPU whose kind is not
+#: here is an error: another chip's numbers would mis-price every plan.
+TPU_HARDWARE: Dict[str, Hardware] = {"TPU v5 lite": TPU_V5E}
+
+
+def base_hardware() -> Hardware:
+    """The static Hardware table of the device JAX runs on: the entry
+    for a TPU's ``device_kind``, ``HOST`` for every other backend."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return HOST
+    try:
+        return TPU_HARDWARE[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware table for TPU device kind {dev.device_kind!r}; "
+            f"known: {sorted(TPU_HARDWARE)}") from None
+
+
 def default_hardware() -> Hardware:
     """The Hardware ``auto``/fig8 predict with: the measured-bandwidth
     calibration when one is cached on disk for this backend
@@ -101,7 +121,7 @@ def default_hardware() -> Hardware:
     cheap JSON read — neither calibration nor the sweep runs unless
     something (fig8, the CLIs) asks explicitly."""
     from repro.sql import calibrate, tune
-    base = TPU_V5E if jax.default_backend() == "tpu" else HOST
+    base = base_hardware()
     return tune.tuned_hardware(calibrate.cached_hardware(base) or base)
 
 

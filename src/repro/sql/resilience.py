@@ -97,7 +97,9 @@ def classify_error(exc: BaseException, during: str = "execute") -> QueryError:
     """Wrap a foreign exception into the taxonomy, chaining ``__cause__``.
 
     ``during`` picks the class for plain exceptions: "plan" -> PlanError,
-    "compile" -> CompileError, anything else -> ExecError.  Allocation
+    "compile" -> CompileError, anything else -> ExecError.  A
+    ``NotImplementedError`` (an unlowerable construct) is a CompileError
+    in any phase.  Allocation
     failures (XLA RESOURCE_EXHAUSTED et al.) map to MemoryPressure
     regardless of phase.  Already-typed errors pass through unchanged.
     BaseExceptions that are not Exceptions (KeyboardInterrupt, SystemExit)
@@ -111,7 +113,10 @@ def classify_error(exc: BaseException, during: str = "execute") -> QueryError:
         wrapped: QueryError = MemoryPressure(msg)
     elif during == "plan":
         wrapped = PlanError(msg)
-    elif during == "compile":
+    elif during == "compile" or isinstance(exc, NotImplementedError):
+        # a construct the backend's compiler cannot lower (a Pallas
+        # kernel's scatter-add or cumsum on a TPU) fails every retry
+        # the same way: surface it, never degrade past it to the host
         wrapped = CompileError(msg)
     elif isinstance(exc, (ValueError, TypeError, KeyError)):
         # the engine raises these for *contract* violations (negative

@@ -421,27 +421,39 @@ class ServingLoop:
         """Admit one request.  Raises typed ``MemoryPressure`` when the
         governor is shedding (at the door, like ``QueryServer.submit``)
         and ``RuntimeError`` when the loop is not running."""
+        return self.submit_many([plan], strategy, deadline_s)[0]
+
+    def submit_many(self, plans: List[Plan], strategy: str = "auto",
+                    deadline_s: Optional[float] = None) -> List[Ticket]:
+        """Admit a burst as ONE arrival: the worker routes every member
+        before it forms a wave, so a burst of at most ``max_batch``
+        shareable plans rides one wave (``submit`` in a loop races the
+        worker, which may dispatch the first plans before the rest
+        arrive).  Admission as :meth:`submit`, per plan."""
         if not self._running:
             raise RuntimeError("ServingLoop is not running (start() it, "
                                "or use it as a context manager)")
-        try:
-            self.server.governor.admit()
-        except RS.MemoryPressure:
-            self.server.stats["sheds"] += 1
-            raise
-        with self._rid_lock:
-            rid = self._next_rid
-            self._next_rid += 1
-        t = Ticket(rid, plan, strategy, deadline_s, time.monotonic())
-        self._inbox.put(t)
-        return t
+        tickets = []
+        for plan in plans:
+            try:
+                self.server.governor.admit()
+            except RS.MemoryPressure:
+                self.server.stats["sheds"] += 1
+                raise
+            with self._rid_lock:
+                rid = self._next_rid
+                self._next_rid += 1
+            tickets.append(Ticket(rid, plan, strategy, deadline_s,
+                                  time.monotonic()))
+        self._inbox.put(tickets)
+        return tickets
 
     # -- worker side ---------------------------------------------------
     def _worker(self) -> None:
         draining = False
         while True:
             timeout = self.former.next_wakeup(time.monotonic())
-            arrivals: List[Ticket] = []
+            arrivals: list = []         # bursts (ticket lists) and _STOP
             try:
                 first = self._inbox.get(
                     timeout=None if timeout is None else min(timeout, 0.05))
@@ -451,12 +463,13 @@ class ServingLoop:
             except queue.Empty:
                 pass
             now = time.monotonic()
-            for t in arrivals:
-                if t is _STOP:
+            for burst in arrivals:
+                if burst is _STOP:
                     draining = True
                     continue
-                self.tracker.note(t.arrival)
-                self._route(t, now)
+                for t in burst:
+                    self.tracker.note(t.arrival)
+                    self._route(t, now)
             while True:
                 wave = self.former.decide(time.monotonic(),
                                           self.tracker.expected_gap(),

@@ -226,6 +226,16 @@ def merge_partials(parts) -> GroupPartial:
 # ---------------------------------------------------------------------------
 
 
+def _place(sdb: ShardedDatabase, stack: np.ndarray) -> jnp.ndarray:
+    """An ``(S, L)`` host stack on the device(s): row ``i`` goes straight
+    to mesh device ``i`` (``jnp.asarray`` would land the whole stack on
+    the first device before ``shard_map`` resharded it)."""
+    if sdb.mesh is None:
+        return jnp.asarray(stack)
+    return jax.device_put(
+        stack, NamedSharding(sdb.mesh, PartitionSpec(SHARD_AXIS, None)))
+
+
 def stacked_stream(sdb: ShardedDatabase, col: str) -> Tuple:
     """``(array, phys, ref)`` of one fact column as the shard_map path
     loads it: an ``(S, L)`` batch whose row ``i`` is shard ``i``'s
@@ -247,7 +257,7 @@ def stacked_stream(sdb: ShardedDatabase, col: str) -> Tuple:
         for i in range(sdb.n_shards):
             seg = vals[b[i]:b[i + 1]]
             out[i, :len(seg)] = seg
-        entry = (jnp.asarray(out), 32, 0)
+        entry = (_place(sdb, out), 32, 0)
     else:
         words = []
         for i in range(sdb.n_shards):
@@ -255,7 +265,7 @@ def stacked_stream(sdb: ShardedDatabase, col: str) -> Tuple:
             seg = vals[b[i]:b[i + 1]]
             padded[:len(seg)] = seg
             words.append(ST.pack_words(padded, enc.width, enc.ref))
-        entry = (jnp.asarray(np.stack(words)), enc.phys, enc.ref)
+        entry = (_place(sdb, np.stack(words)), enc.phys, enc.ref)
     sdb._streams[col] = entry
     return entry
 
@@ -286,14 +296,14 @@ def stacked_window(sdb: ShardedDatabase, col: str, lo: int, hi: int,
         for i in range(sdb.n_shards):
             seg = window(i)
             out[i, :len(seg)] = seg
-        return jnp.asarray(out), 32, 0
+        return _place(sdb, out), 32, 0
     words = []
     for i in range(sdb.n_shards):
         padded = np.full(pad, enc.ref, np.int32)
         seg = window(i)
         padded[:len(seg)] = seg
         words.append(ST.pack_words(padded, enc.width, enc.ref))
-    return jnp.asarray(np.stack(words)), enc.phys, enc.ref
+    return _place(sdb, np.stack(words)), enc.phys, enc.ref
 
 
 def validity_window(sdb: ShardedDatabase, lo: int, hi: int,
@@ -304,7 +314,7 @@ def validity_window(sdb: ShardedDatabase, lo: int, hi: int,
     for i in range(sdb.n_shards):
         n = int(sdb.bounds[i + 1] - sdb.bounds[i])
         v[i, :max(0, min(hi, n) - lo)] = 1
-    return jnp.asarray(v), 32, 0
+    return _place(sdb, v), 32, 0
 
 
 def validity_stream(sdb: ShardedDatabase) -> Tuple:
@@ -316,7 +326,7 @@ def validity_stream(sdb: ShardedDatabase) -> Tuple:
         v = np.zeros((sdb.n_shards, sdb.pad_rows), np.int32)
         for i in range(sdb.n_shards):
             v[i, :int(sdb.bounds[i + 1] - sdb.bounds[i])] = 1
-        sdb._validity = (jnp.asarray(v), 32, 0)
+        sdb._validity = (_place(sdb, v), 32, 0)
     return sdb._validity
 
 

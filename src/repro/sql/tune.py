@@ -519,8 +519,7 @@ def _part_default_bits(n_build: int) -> int:
     the shipped budget, deliberately bypassing any tuned hardware so the
     sweep's baseline is what the engine would do without this module."""
     from repro.sql import model as M
-    base = M.TPU_V5E if jax.default_backend() == "tpu" else M.HOST
-    return M.part_bits(n_build, hw=base)
+    return M.part_bits(n_build, hw=M.base_hardware())
 
 
 def _sweep_part_probe(g: dict, rng, digit: int) -> List[TunedConfig]:

@@ -137,3 +137,49 @@ def test_spja_fused():
         out_r = ref.spja([x], pb, [fk], [htk, htv], mults, m1, mm2,
                          measure_op=mop, n_groups=9)
         np.testing.assert_allclose(out_k, out_r, rtol=1e-5)
+
+
+def test_integer_group_sums_exact_past_f32_and_int32():
+    """Integer measures sum exactly on the XLA path: totals far past
+    2^24 (and 2^31, where an int32 accumulator alone wraps) come out as
+    the f32 rounding of the exact integer total, negative ones too."""
+    rng = np.random.default_rng(5)
+    n = 200_000
+    g = rng.integers(0, 3, n).astype(np.int32)
+    v = rng.integers(0, 100_000, n).astype(np.int32)
+    v[g == 2] *= -1                       # a negative total
+    exact = np.array([v[g == k].astype(np.int64).sum() for k in range(3)])
+    assert np.abs(exact).min() > 2 ** 31
+    got = ref.group_sum(jnp.asarray(g), jnp.asarray(v), 3)
+    np.testing.assert_array_equal(np.asarray(got), exact.astype(np.float32))
+    # the same sums through the blocked SPJA fold (a block size that
+    # leaves a tail), single group and grouped
+    ones = jnp.ones((n,), jnp.int32)
+    bounds = jnp.array([[1, 1]], jnp.int32)
+    old = ops.XLA_BLOCK_ROWS
+    try:
+        ops.XLA_BLOCK_ROWS = 4096
+        got1 = ops.spja([ones], bounds, [], [], jnp.zeros((0,), jnp.int32),
+                        jnp.asarray(v), mode="ref", n_groups=1)
+    finally:
+        ops.XLA_BLOCK_ROWS = old
+    np.testing.assert_array_equal(np.asarray(got1),
+                                  np.float32(exact.sum()))
+
+
+def test_blocked_prefix_sum_and_compaction(monkeypatch):
+    """``prefix_sum``/``compact`` split large inputs into pieces; shrunk
+    piece sizes must give numpy's cumsum and stable compaction."""
+    monkeypatch.setattr(ref, "_SCAN_WINDOW", 8)
+    monkeypatch.setattr(ref, "_SCATTER_BLOCK", 64)
+    rng = np.random.default_rng(6)
+    for n in (7, 64, 65, 1000):
+        x = rng.integers(0, 5, n).astype(np.int32)
+        np.testing.assert_array_equal(ref.prefix_sum(jnp.asarray(x)),
+                                      np.cumsum(x))
+        out, cnt = ref.select_scan(jnp.asarray(x), jnp.arange(n), 2, 3)
+        keep = np.flatnonzero((x >= 2) & (x <= 3))
+        want = np.zeros(n, np.int32)
+        want[:len(keep)] = keep
+        assert int(cnt) == len(keep)
+        np.testing.assert_array_equal(out, want)

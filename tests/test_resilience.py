@@ -79,6 +79,18 @@ def test_classify_wraps_and_chains_cause():
                       RS.CompileError)
 
 
+def test_unlowerable_construct_surfaces_as_compile_error():
+    """A construct the backend cannot lower (the TPU compiler raises
+    NotImplementedError for a Pallas scatter-add) must not be retried
+    down the ladder to the host oracle, where the device would vanish
+    from view: it is a non-retryable CompileError in any phase."""
+    exc = NotImplementedError("Unimplemented primitive in Pallas TPU "
+                              "lowering for KernelType.TC: scatter-add")
+    err = RS.classify_error(exc, during="execute")
+    assert isinstance(err, RS.CompileError) and not err.retryable
+    assert err.__cause__ is exc
+
+
 def test_errorinfo_stringifies_and_supports_substring():
     err = RS.ExecError("boom at morsel 3")
     info = RS.ErrorInfo.from_exception(err, strategy="fused", attempts=2)

@@ -311,3 +311,16 @@ def test_serving_loop_prewarm_counts_buckets():
         assert r.error is None
         np.testing.assert_array_equal(np.asarray(r.result),
                                       oracle(POOL[5]))
+
+
+def test_serving_loop_submit_many_rides_one_wave():
+    # a burst admitted as one arrival is routed whole before the former
+    # decides, so it cannot be split by the worker waking mid-burst
+    burst = POOL[:6]
+    with SV.ServingLoop(DB, mode="ref", max_batch=8, slo_s=5.0) as loop:
+        tickets = loop.submit_many(burst, strategy="shared")
+        results = [t.wait(timeout=120) for t in tickets]
+    assert [r.shared_wave_size for r in results] == [len(burst)] * 6
+    for r, p in zip(results, burst):
+        assert r.error is None and r.strategy == "shared"
+        np.testing.assert_array_equal(np.asarray(r.result), oracle(p))

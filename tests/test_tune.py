@@ -289,7 +289,8 @@ def test_cold_store_launches_default_byte_for_byte(tune_dir):
     cq = compile_plan(QUERIES["q2.1"], "fused")
     got = cq.execute(DB, mode="ref")
     assert cq.launch_config["spja"] == {
-        "tile": DEFAULT_TILE, "width": 32, "source": "default"}
+        "tile": DEFAULT_TILE, "width": 32, "source": "default",
+        "impl": "xla"}
     cq2 = compile_plan(QUERIES["q2.1"], "fused")
     explicit = cq2.execute(DB, mode="ref", tile=DEFAULT_TILE)
     assert cq2.launch_config["spja"]["source"] == "explicit"
@@ -303,12 +304,13 @@ def test_tuned_store_drives_launch_and_preserves_answers(tune_dir):
     cq = compile_plan(QUERIES["q2.1"], "fused")
     got = cq.execute(DB, mode="ref")
     assert cq.launch_config["spja"] == {
-        "tile": 512, "width": 32, "source": "tuned"}
+        "tile": 512, "width": 32, "source": "tuned", "impl": "xla"}
     # explicit tile still wins over the store
     cq2 = compile_plan(QUERIES["q2.1"], "fused")
     exp = cq2.execute(DB, mode="ref", tile=DEFAULT_TILE)
     assert cq2.launch_config["spja"] == {
-        "tile": DEFAULT_TILE, "width": 32, "source": "explicit"}
+        "tile": DEFAULT_TILE, "width": 32, "source": "explicit",
+        "impl": "xla"}
     np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
 
 
