@@ -381,11 +381,12 @@ def _fold_rows(streams, n_rows: int, step, acc):
 
     def full_block(i):
         cols = []
-        for arr, width, ref in streams:
-            n_words = XLA_BLOCK_ROWS * width // 32
-            cols.append(_decode_planes(
-                jax.lax.dynamic_slice(arr, (i * n_words,), (n_words,)),
-                width, ref, XLA_BLOCK_ROWS))
+        with jax.named_scope(_ref.DECODE):
+            for arr, width, ref in streams:
+                n_words = XLA_BLOCK_ROWS * width // 32
+                cols.append(_decode_planes(
+                    jax.lax.dynamic_slice(arr, (i * n_words,), (n_words,)),
+                    width, ref, XLA_BLOCK_ROWS))
         return cols
 
     if n_full:
@@ -394,11 +395,12 @@ def _fold_rows(streams, n_rows: int, step, acc):
     if tail:
         start = n_full * XLA_BLOCK_ROWS
         cols = []
-        for arr, width, ref in streams:
-            per_word = 32 // width
-            w0 = start // per_word
-            cols.append(_decode_stream(arr[w0:w0 + -(-tail // per_word)],
-                                       width, ref, tail))
+        with jax.named_scope(_ref.DECODE):
+            for arr, width, ref in streams:
+                per_word = 32 // width
+                w0 = start // per_word
+                cols.append(_decode_stream(
+                    arr[w0:w0 + -(-tail // per_word)], width, ref, tail))
         acc = step(acc, cols)
     return acc
 

@@ -18,6 +18,13 @@ import jax.numpy as jnp
 from repro.core import blocks as B
 from repro.kernels.common import decode_words
 
+# named scopes of the SPJA phases: they mark the ops of each phase in the
+# compiled program's metadata, where a profiler trace reads them
+DECODE = "spja.decode"
+FILTER = "spja.filter"
+PROBE = "spja.probe"
+AGGREGATE = "spja.aggregate"
+
 
 def unpack(words: jax.Array, n: int, phys: int, ref=0) -> jax.Array:
     """Bit-unpack oracle: first ``n`` values of a packed word stream at
@@ -201,44 +208,48 @@ def multi_spja_sums(pred_cols, pred_bounds, join_keys, join_tables,
     # --- shared once-per-wave work: column predicates stay per-query,
     # but each dim table is probed exactly once for every member ---
     payloads, founds = [], []
-    for j in range(J):
-        payload, found = B.block_lookup(join_keys[j], join_tables[2 * j],
-                                        join_tables[2 * j + 1])
-        payloads.append(payload)
-        founds.append(found)
+    with jax.named_scope(PROBE):
+        for j in range(J):
+            payload, found = B.block_lookup(
+                join_keys[j], join_tables[2 * j], join_tables[2 * j + 1])
+            payloads.append(payload)
+            founds.append(found)
 
     rows = []
     for q in range(Q):
-        bitmap = jnp.full(shape, q_valid[q], jnp.int32)
-        for c in range(C):
-            bitmap = bitmap * ((pred_cols[c] >= pred_bounds[q, c, 0])
-                               & (pred_cols[c] <= pred_bounds[q, c, 1])
-                               ).astype(jnp.int32)
+        with jax.named_scope(FILTER):
+            bitmap = jnp.full(shape, q_valid[q], jnp.int32)
+            for c in range(C):
+                bitmap = bitmap * (
+                    (pred_cols[c] >= pred_bounds[q, c, 0])
+                    & (pred_cols[c] <= pred_bounds[q, c, 1])
+                ).astype(jnp.int32)
         group = jnp.zeros(shape, jnp.int32)
         for j in range(J):
             use = join_use[q, j]
             bitmap = bitmap * (1 - use + use * founds[j])
             group = group + payloads[j] * join_mults[q, j]
-        # measure: data-selected from the stacked measure columns so one
-        # trace serves any member composition
-        m1 = jnp.zeros(shape, mdt)
-        m2 = jnp.zeros(shape, mdt)
-        for m in range(M):
-            m1 = m1 + jnp.where(measure_sel[q, 0] == m,
-                                measure_cols[m].astype(mdt), 0)
-            m2 = m2 + jnp.where(measure_sel[q, 1] == m,
-                                measure_cols[m].astype(mdt), 0)
-        op = measure_sel[q, 2]
+        with jax.named_scope(AGGREGATE):
+            # measure: data-selected from the stacked measure columns so
+            # one trace serves any member composition
+            m1 = jnp.zeros(shape, mdt)
+            m2 = jnp.zeros(shape, mdt)
+            for m in range(M):
+                m1 = m1 + jnp.where(measure_sel[q, 0] == m,
+                                    measure_cols[m].astype(mdt), 0)
+                m2 = m2 + jnp.where(measure_sel[q, 1] == m,
+                                    measure_cols[m].astype(mdt), 0)
+            op = measure_sel[q, 2]
 
-        def pick(forms):                # op: 0 = m1, 1 = m1*m2, 2 = m1-m2
-            return jnp.where(op == 1, forms[1],
-                             jnp.where(op == 2, forms[2], forms[0]))
+            def pick(forms):            # op: 0 = m1, 1 = m1*m2, 2 = m1-m2
+                return jnp.where(op == 1, forms[1],
+                                 jnp.where(op == 2, forms[2], forms[0]))
 
-        mi = _measure(m1, m2, jnp.int32)
-        rows.append(group_sums(group, bitmap,
-                               None if mi is None else pick(mi),
-                               pick(_measure(m1, m2, jnp.float32)),
-                               n_groups))
+            mi = _measure(m1, m2, jnp.int32)
+            rows.append(group_sums(group, bitmap,
+                                   None if mi is None else pick(mi),
+                                   pick(_measure(m1, m2, jnp.float32)),
+                                   n_groups))
     wrapped = (None if rows[0][0] is None
                else jnp.stack([r[0] for r in rows]))
     return wrapped, jnp.stack([r[1] for r in rows])
@@ -369,20 +380,24 @@ def spja_sums(pred_cols, pred_bounds, join_keys, join_tables, group_mults,
               m1, m2, measure_op="first", n_groups=1):
     """Single-query SPJA as a :func:`group_sums` pair (integer measures
     sum exactly)."""
-    bitmap = jnp.ones(m1.shape, jnp.int32)   # rows: any layout
-    for p, col in enumerate(pred_cols):
-        bitmap = bitmap * ((col >= pred_bounds[p, 0])
-                           & (col <= pred_bounds[p, 1])).astype(jnp.int32)
+    with jax.named_scope(FILTER):
+        bitmap = jnp.ones(m1.shape, jnp.int32)   # rows: any layout
+        for p, col in enumerate(pred_cols):
+            bitmap = bitmap * ((col >= pred_bounds[p, 0])
+                               & (col <= pred_bounds[p, 1])
+                               ).astype(jnp.int32)
     group = jnp.zeros(m1.shape, jnp.int32)
     for j, keys in enumerate(join_keys):
-        payload, found = B.block_lookup(keys, join_tables[2 * j],
-                                        join_tables[2 * j + 1])
+        with jax.named_scope(PROBE):
+            payload, found = B.block_lookup(keys, join_tables[2 * j],
+                                            join_tables[2 * j + 1])
         bitmap = bitmap * found
         group = group + payload * group_mults[j]
     if measure_op not in ("mul", "sub"):
         m2 = None
     pick = {"first": 0, "mul": 1, "sub": 2}[measure_op]
-    mi = _measure(m1, m2, jnp.int32)
-    mf = _measure(m1, m2, jnp.float32)
-    return group_sums(group, bitmap, None if mi is None else mi[pick],
-                      mf[pick], n_groups)
+    with jax.named_scope(AGGREGATE):
+        mi = _measure(m1, m2, jnp.int32)
+        mf = _measure(m1, m2, jnp.float32)
+        return group_sums(group, bitmap, None if mi is None else mi[pick],
+                          mf[pick], n_groups)

@@ -71,7 +71,10 @@ stale on it).
 
 ``LAUNCH_STATS`` counts probe/partition dispatches per process so the
 single-launch claim is *observable*: ``part`` issues exactly one probe
-launch per join, ``part_loop`` one per non-empty partition.
+launch per join, ``part_loop`` one per non-empty partition; it also
+counts every host-to-device copy of ``storage.upload`` and its bytes.
+The fused paths run each kernel call under a ``sql.dispatch`` span and
+bring its result to the host under ``sql.pull`` (``repro.sql.spans``).
 """
 from __future__ import annotations
 
@@ -93,6 +96,7 @@ from repro.sql import hashtable as HT
 from repro.sql import morsel as MS
 from repro.sql import plan as P
 from repro.sql import shard as SH
+from repro.sql import spans as SP
 from repro.sql import ssb
 from repro.sql import storage as ST
 
@@ -102,12 +106,8 @@ STRATEGIES = ("fused", "opat", "part", "part_loop", "shared", "sharded",
 _INT32_MIN, _INT32_MAX = -(1 << 31), (1 << 31) - 1
 _MEASURE_OP_CODE = {"first": 0, "mul": 1, "sub": 2}
 
-# process-wide dispatch counters (reset via reset_launch_stats): kernel
-# launches on the join probe path, the overhead axis fig8 attributes the
-# fused-vs-loop win to.  "probe" counts probe-kernel dispatches, "partition"
-# counts radix-shuffle passes, "host_syncs" counts device->host round-trips
-# of probe-side arrays (the loop path's other hidden cost).
-LAUNCH_STATS = {"probe": 0, "partition": 0, "host_syncs": 0}
+# process-wide dispatch and upload counters (``spans.LAUNCH_STATS``)
+LAUNCH_STATS = SP.LAUNCH_STATS
 
 
 def reset_launch_stats() -> Dict[str, int]:
@@ -272,7 +272,7 @@ def _measure_streams(fact, proj):
     m1 = streams[0][0]
     m2 = streams[1][0] if len(streams) == 2 else None
     widths = tuple(w for _, w, _ in streams)
-    refs = jnp.asarray(np.array([r for _, _, r in streams], np.int32))
+    refs = ST.upload(np.array([r for _, _, r in streams], np.int32))
     return m1, m2, widths, refs
 
 
@@ -293,12 +293,12 @@ def _execute_fused(plan: P.Plan, db: ssb.Database, mode: str,
     pred_streams = [ST.column_stream(fact, c) for c, _, _ in bounds]
     pred_cols = [s[0] for s in pred_streams]
     pred_widths = tuple(s[1] for s in pred_streams)
-    pred_bounds = jnp.asarray(_rewritten_bounds(fact, bounds))
+    pred_bounds = ST.upload(_rewritten_bounds(fact, bounds))
     joins = plan.joins
     key_streams = [ST.column_stream(fact, j.fact_col) for j in joins]
     join_keys = [s[0] for s in key_streams]
     key_widths = tuple(s[1] for s in key_streams)
-    key_refs = jnp.asarray(np.array([s[2] for s in key_streams], np.int32))
+    key_refs = ST.upload(np.array([s[2] for s in key_streams], np.int32))
     if prebuilt is not None:
         join_tables = prebuilt
     else:
@@ -307,17 +307,20 @@ def _execute_fused(plan: P.Plan, db: ssb.Database, mode: str,
             htk, htv = (cache.get_or_build(db, j) if cache is not None
                         else HT.build_dim_table(db, j))
             join_tables.extend([htk, htv])
-    mults = jnp.asarray(np.array([j.mult for j in joins], np.int32))
+    mults = ST.upload(np.array([j.mult for j in joins], np.int32))
     proj = plan.project
     m1, m2, m_widths, m_refs = _measure_streams(fact, proj)
     FLT.maybe_fault("kernel")
-    out = ops.spja(pred_cols, pred_bounds, join_keys, join_tables, mults,
-                   m1, m2, measure_op=proj.op, n_groups=plan.n_groups,
-                   mode=mode, tile=_launch("spja", tile, mode=mode),
-                   pred_widths=pred_widths,
-                   key_widths=key_widths, key_refs=key_refs,
-                   m_widths=m_widths, m_refs=m_refs, n_rows=fact.n_rows)
-    return np.asarray(out)
+    with SP.span(SP.DISPATCH):
+        out = ops.spja(pred_cols, pred_bounds, join_keys, join_tables,
+                       mults, m1, m2, measure_op=proj.op,
+                       n_groups=plan.n_groups, mode=mode,
+                       tile=_launch("spja", tile, mode=mode),
+                       pred_widths=pred_widths,
+                       key_widths=key_widths, key_refs=key_refs,
+                       m_widths=m_widths, m_refs=m_refs, n_rows=fact.n_rows)
+    with SP.span(SP.PULL):
+        return np.asarray(out)
 
 
 def _fused_scan_cols(plan: P.Plan) -> List[str]:
@@ -722,7 +725,7 @@ def shared_params(plans: List[P.Plan], db: ssb.Database,
     key_streams = [ST.column_stream(fact, j.fact_col) for j in join_nodes]
     join_keys = [s[0] for s in key_streams]
     key_widths = tuple(s[1] for s in key_streams)
-    key_refs = jnp.asarray(np.array([s[2] for s in key_streams], np.int32))
+    key_refs = ST.upload(np.array([s[2] for s in key_streams], np.int32))
     join_tables: List[jnp.ndarray] = []
     for j in join_nodes:
         k = shared_join_key(j)
@@ -745,15 +748,15 @@ def shared_params(plans: List[P.Plan], db: ssb.Database,
     m_streams = [ST.column_stream(fact, c) for c in mcol_ix]
     measure_cols = [arr for arr, _, _ in m_streams]
     m_widths = tuple(w for _, w, _ in m_streams)
-    m_refs = jnp.asarray(np.array([r for _, _, r in m_streams], np.int32))
+    m_refs = ST.upload(np.array([r for _, _, r in m_streams], np.int32))
 
     q_valid = np.zeros(q_pad, np.int32)
     q_valid[:q_n] = 1
     n_groups = max(plan.n_groups for plan in foot)
     pred_streams = [ST.column_stream(fact, c) for c in col_ix]
-    args = ([s[0] for s in pred_streams], jnp.asarray(bounds),
-            join_keys, join_tables, jnp.asarray(mults), jnp.asarray(use),
-            jnp.asarray(q_valid), measure_cols, jnp.asarray(msel))
+    args = ([s[0] for s in pred_streams], ST.upload(bounds),
+            join_keys, join_tables, ST.upload(mults), ST.upload(use),
+            ST.upload(q_valid), measure_cols, ST.upload(msel))
     kwargs = dict(pred_widths=tuple(s[1] for s in pred_streams),
                   key_widths=key_widths, key_refs=key_refs,
                   m_widths=m_widths, m_refs=m_refs, n_rows=fact.n_rows)
@@ -833,8 +836,11 @@ def execute_shared_morsels(plans: List[P.Plan], db: ssb.Database,
             fact=m.table, anchor=anchor)
         LAUNCH_STATS["probe"] += 1      # one whole-wave launch per morsel
         FLT.maybe_fault("kernel")
-        return np.asarray(ops.multi_spja(*args, n_groups=n_groups,
-                                         mode=mode, tile=tile, **kwargs))
+        with SP.span(SP.DISPATCH):
+            out = ops.multi_spja(*args, n_groups=n_groups, mode=mode,
+                                 tile=tile, **kwargs)
+        with SP.span(SP.PULL):
+            return np.asarray(out)
 
     partials = stream.fold(run, report)
     out = partials[0] if len(partials) == 1 else SH.tree_merge(partials)
@@ -1345,9 +1351,10 @@ class CompiledQuery:
         strategy = self.strategy
         if strategy == "auto":
             from repro.sql import model as M
-            choice = M.choose(self.plan, db,
-                              n_shards=SH.shard_count(db),
-                              morsel_bytes=morsel_bytes)
+            with SP.span(SP.PLAN):
+                choice = M.choose(self.plan, db,
+                                  n_shards=SH.shard_count(db),
+                                  morsel_bytes=morsel_bytes)
             strategy = choice.strategy
             self.predictions = choice.predictions
         self.decided = strategy

@@ -25,7 +25,9 @@ import numpy as np
 
 from repro.core.blocks import EMPTY   # probe kernels compare against this
 from repro.sql import plan as P
+from repro.sql import spans as SP
 from repro.sql import ssb
+from repro.sql import storage as ST
 from repro.sql.storage import PackedTable
 
 
@@ -120,7 +122,7 @@ def build_dim_table(db: ssb.Database, join: P.HashJoin
     n_slots = table_slots(keys, np.asarray(
         getattr(db, join.dim)[join.key_col]))
     htk, htv = np_build(keys, vals, n_slots)
-    return jnp.asarray(htk), jnp.asarray(htv)
+    return ST.upload(htk), ST.upload(htv)
 
 
 @dataclass(frozen=True)
@@ -181,7 +183,7 @@ def build_dim_partitions(db: ssb.Database, join: P.HashJoin, bits: int,
         parts: List[Tuple[jnp.ndarray, jnp.ndarray]] = []
         for kp, vp in _bucket_runs(keys, vals, bits):
             htk, htv = np_build(kp, vp, next_pow2(max(len(kp), 1)))
-            parts.append((jnp.asarray(htk), jnp.asarray(htv)))
+            parts.append((ST.upload(htk), ST.upload(htv)))
         return parts
     counts = np.bincount(keys & ((1 << bits) - 1), minlength=1 << bits)
     n_slots = next_pow2(max(int(counts.max()) if len(keys) else 0, 1))
@@ -189,7 +191,7 @@ def build_dim_partitions(db: ssb.Database, join: P.HashJoin, bits: int,
     htv = np.zeros((1 << bits, n_slots), np.int32)
     for p, (kp, vp) in enumerate(_bucket_runs(keys, vals, bits)):
         htk[p], htv[p] = np_build(kp, vp, n_slots)
-    return PackedParts(jnp.asarray(htk), jnp.asarray(htv))
+    return PackedParts(ST.upload(htk), ST.upload(htv))
 
 
 def join_cache_key(join: P.HashJoin) -> Tuple:
@@ -330,20 +332,21 @@ class HashTableCache:
 
     def get_or_build(self, db: ssb.Database, join: P.HashJoin
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        self._bind(db)
-        key = join_cache_key(join)
-        hit = self.tables.get(key)
-        if hit is not None:
-            self.hits += 1
-            self._touch(key)
-            return hit
-        self.misses += 1
-        built = build_dim_table(db, join)
-        if _cacheable(key):
-            self.tables[key] = built
-            self._dims.add(join.dim)
-            self._touch(key)
-        return built
+        with SP.span(SP.HASHTABLE):
+            self._bind(db)
+            key = join_cache_key(join)
+            hit = self.tables.get(key)
+            if hit is not None:
+                self.hits += 1
+                self._touch(key)
+                return hit
+            self.misses += 1
+            built = build_dim_table(db, join)
+            if _cacheable(key):
+                self.tables[key] = built
+                self._dims.add(join.dim)
+                self._touch(key)
+            return built
 
     def get_build_count(self, db: ssb.Database, join: P.HashJoin) -> int:
         """Filtered build-side row count, memoized under the join's

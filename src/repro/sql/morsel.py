@@ -43,8 +43,6 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Iterable, Iterator, List, Optional, Tuple
 
-import jax
-
 from repro.sql import storage as ST
 
 # Morsel cuts land on multiples of LANE rows: one int32-word boundary of
@@ -246,8 +244,9 @@ class MorselStream:
 
     def _prefetch(self, m: Morsel) -> None:
         """Issue the async host→device copy of the next morsel's scanned
-        columns (jax transfers are asynchronous: ``device_put`` returns
-        immediately and overlaps with the in-flight compute)."""
+        columns (``storage.upload``; jax transfers are asynchronous: the
+        call returns once the copy is issued and overlaps with the
+        in-flight compute)."""
         from repro.sql import faults
         faults.maybe_fault("upload")
         table = m.table
@@ -257,12 +256,12 @@ class MorselStream:
             for c in names:
                 col = table.columns[c]
                 if col._words_jax is None:
-                    col._words_jax = jax.device_put(col.words)
+                    col._words_jax = ST.upload(col.words)
         else:
-            # plain tables upload inside the executor's jnp.asarray;
+            # plain tables upload inside the executor's column_stream;
             # issue the same transfers early
             for c in names:
-                jax.device_put(table.columns[c])
+                ST.upload(table.columns[c])
 
     def _release(self, m: Morsel, keep: Optional[Morsel]) -> None:
         """Drop a finished morsel's device buffers and decode memos —

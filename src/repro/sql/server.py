@@ -93,6 +93,7 @@ import numpy as np
 from repro.sql import compile as C
 from repro.sql import resilience as RS
 from repro.sql import result_cache as RC
+from repro.sql import spans as SP
 from repro.sql import ssb
 from repro.sql import storage as ST
 from repro.sql.compile import compile_plan, shareability
@@ -158,6 +159,10 @@ class QueryResult:
     #   query answered by masking a containing cached grid — implies
     #   cache_hit; benchmarks assert these answers against the oracle
     #   so cache correctness under pressure/eviction stays observable
+    upload_bytes: Optional[int] = None  # host-to-device bytes the
+    #   execution copied (``LAUNCH_STATS["upload_bytes"]``' change): plain
+    #   columns, parameter arrays, dimension tables built on a miss; for a
+    #   shared member the whole wave's; None for a result-cache answer
 
 
 class QueryServer:
@@ -440,10 +445,18 @@ class QueryServer:
         ``sharded=True`` runs the wave once per fact shard and merges
         the stacked partial grids (``compile.execute_shared_sharded``);
         members then also report ``device_count``/``shard_times_s``."""
+        rids = "-".join(str(r.rid) for r in wave)
+        with SP.span(SP.WAVE, rids=rids):
+            return self._shared_pass(wave, model_predictions, sharded)
+
+    def _shared_pass(self, wave: List[QueryRequest],
+                     model_predictions: Optional[Dict[str, float]],
+                     sharded: bool) -> Dict[int, QueryResult]:
         from repro.sql import model as M
         from repro.sql import shard as SH
         out: Dict[int, QueryResult] = {}
         t0 = time.perf_counter()
+        u0 = SP.LAUNCH_STATS["upload_bytes"]
         survivors: List[QueryRequest] = []
         deltas: Dict[int, Tuple[int, int]] = {}
         # built tables collected here ride into execute_shared as-is, so
@@ -528,7 +541,7 @@ class QueryServer:
                 n_morsels=None if report is None else report.n_morsels,
                 peak_resident_bytes=(None if report is None
                                      else report.peak_resident_bytes),
-                launch_config=wave_config)
+                launch_config=wave_config, upload_bytes=uploaded)
 
         # pow2 member-count buckets (like the LM server's length buckets):
         # padded slots are inert but not free, so a small wave must not
@@ -562,6 +575,7 @@ class QueryServer:
                 out[req.rid] = self._execute(req)
             return out
         dt = time.perf_counter() - t0
+        uploaded = SP.LAUNCH_STATS["upload_bytes"] - u0
         self.stats["shared_waves"] += 1
         if sharded:
             self.stats["sharded_waves"] += 1
@@ -601,6 +615,10 @@ class QueryServer:
         return np.asarray(E.run_query_oracle(base, plan))
 
     def _execute(self, req: QueryRequest) -> QueryResult:
+        with SP.span(SP.QUERY, rid=req.rid):
+            return self._ladder(req)
+
+    def _ladder(self, req: QueryRequest) -> QueryResult:
         """One request through the retry/degradation ladder.
 
         Fault-isolated AND deadline-bounded: a non-retryable failure
@@ -615,6 +633,7 @@ class QueryServer:
         rung once before degrading.  Every path terminates: success,
         typed error, or ``DeadlineExceeded``."""
         h0, m0 = self.cache.hits, self.cache.misses
+        u0 = SP.LAUNCH_STATS["upload_bytes"]
         t0 = time.perf_counter()
         cached = self._from_result_cache(req, t0)
         if cached is not None:          # no scan, no ladder: the answer
@@ -638,7 +657,8 @@ class QueryServer:
                 cache_misses=self.cache.misses - m0,
                 attempts=max(attempts, 1),
                 error=RS.ErrorInfo.from_exception(
-                    err, strategy=strategy, attempts=max(attempts, 1)))
+                    err, strategy=strategy, attempts=max(attempts, 1)),
+                upload_bytes=SP.LAUNCH_STATS["upload_bytes"] - u0)
 
         def succeeded(result, ran, cq):
             dt = time.perf_counter() - t0
@@ -674,7 +694,8 @@ class QueryServer:
                 peak_resident_bytes=(None if cq is None
                                      else cq.peak_resident_bytes),
                 launch_config=(None if cq is None
-                               else cq.launch_config))
+                               else cq.launch_config),
+                upload_bytes=SP.LAUNCH_STATS["upload_bytes"] - u0)
 
         ladder = RS.ladder_for(req.strategy)
         predictions: Optional[Dict[str, float]] = None
@@ -720,7 +741,8 @@ class QueryServer:
                 else:
                     # compilation is validation + a dataclass — cheap
                     try:
-                        cq = compile_plan(req.plan, rung)
+                        with SP.span(SP.PLAN):
+                            cq = compile_plan(req.plan, rung)
                     except Exception as e:
                         raise RS.classify_error(e, during="compile") \
                             from e

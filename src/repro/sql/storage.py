@@ -47,10 +47,12 @@ from __future__ import annotations
 from dataclasses import dataclass, field, replace
 from typing import Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.common import gather_decode
+from repro.sql import spans as SP
 from repro.sql import ssb
 
 PHYS_WIDTHS = (1, 2, 4, 8, 16, 32)      # divisors of 32: lane-aligned decode
@@ -267,7 +269,7 @@ class PackedColumn:
         """The packed word stream as a device array (memoized so a
         resident database uploads each column once)."""
         if self._words_jax is None:
-            self._words_jax = jnp.asarray(self.words)
+            self._words_jax = upload(self.words)
         return self._words_jax
 
 
@@ -380,13 +382,28 @@ def encoding_of(table, col: str) -> Optional[ColumnEncoding]:
     return None
 
 
+def upload(x) -> jnp.ndarray:
+    """``jnp.asarray(x)``, the one host-to-device copy of the query path:
+    under a ``sql.upload`` span, counted in ``LAUNCH_STATS``' ``uploads``
+    and ``upload_bytes``.  A device array passes through uncounted.  The
+    span ends when the copy is issued: a transfer that completes later
+    is waited for by whoever reads its result."""
+    if isinstance(x, jax.Array):
+        return x
+    with SP.span(SP.UPLOAD):
+        out = jnp.asarray(x)
+    SP.LAUNCH_STATS["uploads"] += 1
+    SP.LAUNCH_STATS["upload_bytes"] += out.nbytes
+    return out
+
+
 def column_stream(table, col: str) -> Tuple[jnp.ndarray, int, int]:
     """``(array, phys, ref)`` as the kernels load it: the packed word
     stream for a packed column, the plain int32 column (phys=32, ref=0)
     otherwise."""
     enc = encoding_of(table, col)
     if enc is None or enc.kind == "plain":
-        return jnp.asarray(table[col]), 32, 0
+        return upload(table[col]), 32, 0
     return table.columns[col].words_jax(), enc.phys, enc.ref
 
 
