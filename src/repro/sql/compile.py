@@ -260,8 +260,9 @@ def _rewritten_bounds(fact, bounds) -> np.ndarray:
 
 
 def _measure_streams(fact, proj):
-    """The measure inputs as the kernels consume them: the packed word
-    stream for an encoded column, the f32-cast plain column otherwise.
+    """The measure inputs as the kernels consume them: each column's
+    ``(words, phys, ref)`` stream (``storage.column_stream``; a plain
+    column is its int32 values at phys=32).
     Returns (m1, m2, m_widths, m_refs).  Stream count follows the
     measure *op*, matching the kernels' accounting — an m2 on an
     op="first" projection is ignored (never loaded), as it always was

@@ -254,9 +254,7 @@ class MorselStream:
                  else list(table.columns))
         if isinstance(table, ST.PackedTable):
             for c in names:
-                col = table.columns[c]
-                if col._words_jax is None:
-                    col._words_jax = ST.upload(col.words)
+                table.columns[c].words_jax()
         else:
             # plain tables upload inside the executor's column_stream;
             # issue the same transfers early

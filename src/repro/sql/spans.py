@@ -32,7 +32,10 @@ PULL = "sql.pull"
 # passes on the join probe path, the overhead axis fig8 attributes the
 # fused-vs-loop win to; "host_syncs" counts device->host round-trips of
 # probe-side arrays (the loop path's other hidden cost); "uploads" and
-# "upload_bytes" count the host->device copies of ``storage.upload``.
+# "upload_bytes" count the host->device copies of ``storage.upload``;
+# "streams" counts ``storage.column_stream`` calls and "streams_resident"
+# those served by a column buffer already on the device.
 LAUNCH_STATS = {"probe": 0, "partition": 0, "host_syncs": 0,
-                "uploads": 0, "upload_bytes": 0}
+                "uploads": 0, "upload_bytes": 0,
+                "streams": 0, "streams_resident": 0}
 

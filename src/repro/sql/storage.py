@@ -31,7 +31,10 @@ Decode has three consumers, and only the first ever materializes:
   * ``column_stream`` — the (words, phys, ref) triple the packed-aware
     kernels (``kernels/ssb_fused``, ``kernels/multi_fused``,
     ``kernels/select_scan``) load per tile and shift/mask-decode in
-    registers, never writing the decoded column to HBM.
+    registers, never writing the decoded column to HBM.  Every column
+    of a packed table, plain ones included, is served from its memoized
+    device buffer (``PackedColumn.words_jax``): a resident database
+    copies nothing but a query's parameters to the device.
   * ``take`` — positional gather-decode for the operator-at-a-time
     paths: gathers the *words* the row ids touch and decodes in
     registers, so opat/part on a packed database also never stream a
@@ -265,9 +268,16 @@ class PackedColumn:
     def __len__(self) -> int:
         return self.encoding.n_rows
 
+    @property
+    def resident(self) -> bool:
+        """Whether the word stream is on the device (``words_jax`` then
+        copies nothing)."""
+        return self._words_jax is not None
+
     def words_jax(self) -> jnp.ndarray:
-        """The packed word stream as a device array (memoized so a
-        resident database uploads each column once)."""
+        """The packed word stream (a plain column's int32 values) as a
+        device array, memoized so a resident database uploads each
+        column once."""
         if self._words_jax is None:
             self._words_jax = upload(self.words)
         return self._words_jax
@@ -398,13 +408,21 @@ def upload(x) -> jnp.ndarray:
 
 
 def column_stream(table, col: str) -> Tuple[jnp.ndarray, int, int]:
-    """``(array, phys, ref)`` as the kernels load it: the packed word
-    stream for a packed column, the plain int32 column (phys=32, ref=0)
-    otherwise."""
+    """``(array, phys, ref)`` as the kernels load it.  A column of a
+    packed table comes from its memoized device buffer
+    (``PackedColumn.words_jax``; a plain column's words are its int32
+    values, phys=32, ref=0), uploaded on the first call only; a plain
+    ``ssb.Table`` has no buffer to keep, and uploads its int32 column
+    on every call.  Counted in ``LAUNCH_STATS``: ``streams`` every call,
+    ``streams_resident`` those served by a buffer already on the
+    device."""
+    SP.LAUNCH_STATS["streams"] += 1
     enc = encoding_of(table, col)
-    if enc is None or enc.kind == "plain":
+    if enc is None:
         return upload(table[col]), 32, 0
-    return table.columns[col].words_jax(), enc.phys, enc.ref
+    pc = table.columns[col]
+    SP.LAUNCH_STATS["streams_resident"] += pc.resident
+    return pc.words_jax(), enc.phys, enc.ref
 
 
 def take(table, col: str, rowids: jnp.ndarray) -> jnp.ndarray:

@@ -12,7 +12,11 @@
 * encoded-domain predicate rewrite, encoded-bytes cost model, packed
   database through the QueryServer (bytes_scanned reporting, fingerprint
   compatibility with the plain original)
+* plain columns of a packed table served from their resident device
+  buffer: counted, dropped with a morsel cut, never stale after ingest
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -22,6 +26,8 @@ from repro.kernels import ops
 from repro.sql import compile as C
 from repro.sql import engine, ssb
 from repro.sql import model as M
+from repro.sql import morsel as MS
+from repro.sql import spans as SP
 from repro.sql import storage as ST
 from repro.sql.compile import compile_plan
 from repro.sql.plan import ColExpr, QueryBuilder
@@ -383,3 +389,143 @@ def test_ops_spja_dispatch_guards():
                  jnp2.zeros((0,), jnp2.int32), col.words_jax(), None,
                  measure_op="first", mode="ref",
                  m_widths=(col.encoding.phys,))
+
+
+# ---------------------------------------------------------------------------
+# plain columns served from their resident device buffer
+# ---------------------------------------------------------------------------
+
+# the measures at a width past 16 bits, so they stay plain as at the
+# SSB's own widths (``lo_extendedprice`` is 24 bits there)
+PLAIN_MEASURES = ("lo_extendedprice", "lo_revenue", "lo_supplycost")
+
+
+def _wide_measures_db() -> ssb.Database:
+    base = ssb.generate(sf=0.005, seed=3)
+    for c in PLAIN_MEASURES:
+        base.lineorder.columns[c] = base.lineorder.columns[c] * 1000
+    return base
+
+
+WIDE = _wide_measures_db()
+
+
+def _packed_wide() -> ssb.Database:
+    pdb = ST.pack_database(WIDE)
+    assert all(pdb.lineorder.encoding(c).kind == "plain"
+               for c in PLAIN_MEASURES)
+    return pdb
+
+
+def _stats_delta(before):
+    return {k: SP.LAUNCH_STATS[k] - before[k]
+            for k in ("streams", "streams_resident", "uploads",
+                      "upload_bytes")}
+
+
+def test_plain_column_of_packed_table_streams_from_resident_buffer():
+    fact = _packed_wide().lineorder
+    col = "lo_extendedprice"
+    before = dict(SP.LAUNCH_STATS)
+    first, phys, ref = ST.column_stream(fact, col)
+    assert (phys, ref) == (32, 0)
+    np.testing.assert_array_equal(np.asarray(first), fact[col])
+    assert _stats_delta(before) == {"streams": 1, "streams_resident": 0,
+                                    "uploads": 1,
+                                    "upload_bytes": 4 * fact.n_rows}
+    before = dict(SP.LAUNCH_STATS)
+    again, _, _ = ST.column_stream(fact, col)
+    assert again is first
+    assert _stats_delta(before) == {"streams": 1, "streams_resident": 1,
+                                    "uploads": 0, "upload_bytes": 0}
+
+
+def test_plain_table_uploads_on_every_stream():
+    fact = WIDE.lineorder
+    col = "lo_extendedprice"
+    arrays = []
+    for _ in range(2):
+        before = dict(SP.LAUNCH_STATS)
+        arr, phys, ref = ST.column_stream(fact, col)
+        arrays.append(arr)
+        assert (phys, ref) == (32, 0)
+        assert _stats_delta(before) == {"streams": 1, "streams_resident": 0,
+                                        "uploads": 1,
+                                        "upload_bytes": 4 * fact.n_rows}
+    assert arrays[0] is not arrays[1]
+
+
+def test_morsel_release_drops_a_cuts_plain_buffer_and_keeps_the_bases():
+    fact = _packed_wide().lineorder
+    col = "lo_extendedprice"
+    stream = MS.MorselStream(fact, morsel_bytes=fact.nbytes // 4)
+    cut = list(stream.morsels())[1]
+    assert cut.table is not fact
+    stream._prefetch(cut)
+    assert cut.table.columns[col].resident
+    before = dict(SP.LAUNCH_STATS)
+    ST.column_stream(cut.table, col)
+    assert _stats_delta(before)["streams_resident"] == 1
+    stream._release(cut, keep=None)
+    assert not cut.table.columns[col].resident
+
+    whole = MS.MorselStream(fact)               # one morsel: the table
+    (m,) = whole.morsels()
+    assert m.table is fact
+    whole._prefetch(m)
+    whole._release(m, keep=None)
+    assert fact.columns[col].resident
+
+
+def test_ingested_rows_are_read_not_a_stale_buffer():
+    pdb = _packed_wide()
+    plan = QUERIES["q1.1"]                      # reads the plain price
+    cq = compile_plan(plan, "fused")
+    before = np.asarray(cq.execute(pdb, mode="ref"))
+    assert pdb.lineorder.columns["lo_extendedprice"].resident
+    rng = np.random.default_rng(7)
+    idx = rng.integers(0, WIDE.lineorder.n_rows, 512)
+    rows = {c: np.asarray(WIDE.lineorder[c])[idx]
+            for c in WIDE.lineorder.columns}
+    ST.append_rows(pdb.lineorder, rows)
+    merged = dataclasses.replace(WIDE, lineorder=ssb.Table(
+        "lineorder", {c: np.concatenate([np.asarray(WIDE.lineorder[c]),
+                                         rows[c]])
+                      for c in WIDE.lineorder.columns}))
+    want = engine.run_query_oracle(merged, plan)
+    appended = np.asarray(cq.execute(pdb, mode="ref"))
+    assert not np.array_equal(appended, before)
+    np.testing.assert_allclose(appended, want, rtol=1e-5, atol=1e-3)
+    flushed = dataclasses.replace(
+        pdb, lineorder=ST.flush_deltas(pdb.lineorder))
+    assert not flushed.lineorder.columns["lo_extendedprice"].resident
+    got = np.asarray(cq.execute(flushed, mode="ref"))
+    np.testing.assert_array_equal(got, appended)
+
+
+@pytest.mark.parametrize("strategy", ["fused", "shared"])
+def test_repeat_runs_on_resident_plain_columns_match_oracle(strategy):
+    """Every SSB query twice on one packed database: the second pass
+    reads every column from its resident buffer, and both passes equal
+    the plain database's answers (which upload anew) and the oracle."""
+    pdb = _packed_wide()
+    plans = list(QUERIES.values())
+
+    def run(db):
+        if strategy == "shared":
+            return C.execute_shared(plans, db, mode="ref", pad_to=16)
+        return [compile_plan(p, "fused").execute(db, mode="ref")
+                for p in plans]
+
+    plain = run(WIDE)
+    for rep in range(2):
+        before = dict(SP.LAUNCH_STATS)
+        got = run(pdb)
+        moved = _stats_delta(before)
+        if rep:
+            assert moved["streams_resident"] == moved["streams"] > 0
+        for plan, a, b in zip(plans, got, plain):
+            assert np.array_equal(a, b), (plan.name, rep)
+            np.testing.assert_allclose(
+                a, engine.run_query_oracle(WIDE, plan), rtol=1e-5,
+                atol=1e-3, err_msg=f"{plan.name} rep {rep}")
