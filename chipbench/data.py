@@ -18,9 +18,21 @@ TPC-H's dbgen), in the dictionary codes the program's plans use:
   dates 30 to 90 days later; every other drawn column uniform over the
   configuration's domain; dimension keys dense from 0.
 
-``generate`` returns plain numpy tables; ``to_program`` hands the
-program a bit-packed copy, encoded as ``storage.pack_database`` encodes
-it, while the plain arrays stay with the benchmark for the reference.
+``generate`` returns plain numpy tables, which stay with the benchmark
+for the reference; ``load`` is every caller's set-up: it draws them,
+hands the program its database in the configuration's ``layout``, and
+places it on the device by ``fact_morsel_bytes``:
+
+    layout             "packed" (the default): each column encoded as
+                       ``storage.pack_database`` encodes it;
+                       "plain": every column one int32 word a row, as
+                       Crystal stores them, sharing the drawn buffers
+    fact_morsel_bytes  absent (the default): every table resident on
+                       the device, the fact table streamed as one
+                       morsel; a positive integer: the dimensions
+                       resident, the fact table held on the host and
+                       streamed in morsels of that many bytes
+
 The draws go in chunks of rows, each from a stream of its own spawned
 from the seed, on threads (numpy releases the interpreter lock): the
 same seed gives the same tables on any number of threads.
@@ -28,8 +40,10 @@ same seed gives the same tables on any number of threads.
 from __future__ import annotations
 
 import os
+import sys
+import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -39,6 +53,15 @@ CHUNK_ROWS = 1 << 22            # a multiple of 32: chunks pack on words
 LAST_ORDER_DAYS = 151           # orders end this many days before the end
 COMMIT_DAYS = (30, 90)
 MAX_LINES = 7
+
+DIMENSIONS = ("date", "supplier", "customer", "part")
+LAYOUTS = ("packed", "plain")
+# every key a configuration file may hold; the loader refuses others, so
+# a misspelt key cannot fall back to a default unseen
+KEYS = frozenset({
+    "name", "source", "sf", "reduced", "rows", "lineorder", "derived",
+    "calendar", "dictionary", "storage", "guarantee", "deployment",
+    "assumed", "limits", "layout", "fact_morsel_bytes"})
 
 
 def date_table(cal: dict) -> Dict[str, np.ndarray]:
@@ -187,24 +210,77 @@ def pack(values: np.ndarray, pool):
     return storage.PackedColumn(enc, np.concatenate(list(parts)))
 
 
-def to_program(tables: Tables, sf: float):
+def plain(values: np.ndarray):
+    """``values`` as one int32 word a row, Crystal's layout: the drawn
+    buffer itself, not a copy."""
+    from repro.sql import storage
+    return storage.PackedColumn(
+        storage.ColumnEncoding("plain", 32, 32, 0, len(values)), values)
+
+
+def to_program(tables: Tables, sf: float, layout: str = "packed"):
     """The program's database: every table bit-packed as
-    ``storage.pack_database`` packs it."""
+    ``storage.pack_database`` packs it, or with ``layout="plain"`` every
+    column plain."""
     from repro.sql import ssb, storage
     with _pool() as pool:
+        column = plain if layout == "plain" else (lambda v: pack(v, pool))
         return ssb.Database(**{
-            name: storage.PackedTable(name, {c: pack(v, pool)
+            name: storage.PackedTable(name, {c: column(v)
                                              for c, v in cols.items()})
             for name, cols in tables.items()}, sf=sf)
 
 
-def make_resident(db) -> int:
-    """Upload every column of every table to the device, as a database
-    resident on the chip holds it, and wait for the copies; returns the
-    bytes uploaded."""
+def make_resident(db, names=("lineorder",) + DIMENSIONS) -> int:
+    """Upload every column of the tables ``names`` to the device, as a
+    database resident on the chip holds it, and wait for the copies;
+    returns the bytes uploaded."""
     import jax
-    arrays = [col.words_jax() for name in ("lineorder", "date", "supplier",
-                                           "customer", "part")
+    arrays = [col.words_jax() for name in names
               for col in getattr(db, name).columns.values()]
     jax.block_until_ready(arrays)
     return sum(int(a.nbytes) for a in arrays)
+
+
+def placement(cfg: dict) -> Tuple[str, Optional[int]]:
+    """``cfg``'s ``layout`` and ``fact_morsel_bytes`` (None when
+    absent); a key the loader does not know, or a value outside these,
+    raises ``ValueError`` naming the key."""
+    unknown = sorted(set(cfg) - KEYS)
+    if unknown:
+        raise ValueError(f"unknown configuration keys {unknown}; "
+                         f"known: {sorted(KEYS)}")
+    layout = cfg.get("layout", "packed")
+    if layout not in LAYOUTS:
+        raise ValueError(f"layout must be one of {LAYOUTS}, got {layout!r}")
+    morsel = cfg.get("fact_morsel_bytes")
+    if "fact_morsel_bytes" in cfg and (type(morsel) is not int
+                                       or morsel <= 0):
+        raise ValueError(f"fact_morsel_bytes must be a positive integer, "
+                         f"got {morsel!r}")
+    return layout, morsel
+
+
+def load(cfg: dict, seed: int) -> Tuple[Tables, dict]:
+    """One run's set-up: the tables drawn from ``seed``, and the keyword
+    arguments of the ``QueryServer`` that serves the program's database,
+    built by ``cfg``'s ``layout`` and placed by its ``fact_morsel_bytes``.
+    The uploads are waited for."""
+    layout, morsel_bytes = placement(cfg)
+    t0 = time.perf_counter()
+
+    def log(what: str) -> None:
+        print(f"{what} s={time.perf_counter() - t0:.3f}", file=sys.stderr,
+              flush=True)
+
+    tables = generate(cfg, seed)
+    log(f"generated seed={seed}")
+    db = to_program(tables, cfg["sf"], layout)
+    log(f"{layout} fact_bytes={db.lineorder.nbytes}")
+    if morsel_bytes is None:        # the whole fact table is one morsel
+        resident = make_resident(db)
+        morsel_bytes = db.lineorder.nbytes
+    else:                           # a morsel is a fresh slice: upload none
+        resident = make_resident(db, DIMENSIONS)
+    log(f"resident bytes={resident} morsel_bytes={morsel_bytes}")
+    return tables, {"db": db, "mode": "auto", "morsel_bytes": morsel_bytes}

@@ -3,10 +3,10 @@
     python3 chipbench/limits.py --workload ssb_sf10.joins \
         --seeds 11 12 13 --control-seeds 11 12
 
-For each seed: draw and pack the cell's tables, send each distinct query
-of its mix once through the timed path (``QueryServer.submit`` then
-``run``, the cell's strategy, every table resident) and compare every
-answer with the plain reference, as a run does.  For each control seed,
+For each seed: set up the cell's tables as a run does (``data.load``),
+send each distinct query of its mix once through the timed path
+(``QueryServer.submit`` then ``run``, the cell's strategy) and compare
+every answer with the plain reference, as a run does.  For each control seed,
 also compare the control with the reference: the reference with its
 sums accumulated in float32 (``f32``).  Gaps are ``reference.gap``'s
 float32 steps.  One JSON line per seed, then a summary: the lower
@@ -37,10 +37,8 @@ def readings(cell: spec.Cell, cfg: dict, mix: dict, seed: int,
     from repro.sql import engine
     from repro.sql.server import QueryServer
     t0 = time.perf_counter()
-    tables = data.generate(cfg, seed)
-    db = data.to_program(tables, cfg["sf"])
-    data.make_resident(db)
-    server = QueryServer(db, mode="auto", morsel_bytes=db.lineorder.nbytes)
+    tables, placed = data.load(cfg, seed)
+    server = QueryServer(**placed)
     plans = engine.ssb_queries()
     names = loadgen.distinct(mix)
     got, faults = {}, 0
@@ -49,7 +47,7 @@ def readings(cell: spec.Cell, cfg: dict, mix: dict, seed: int,
         (r,) = server.run().values()
         got[name] = r.result
         faults += bool(run.faults(r, mix["strategy"]))
-    del server, db
+    del server, placed
     gc.collect()
     want = reference.answers(tables, names)
     out = {"seed": seed, "faults": faults,

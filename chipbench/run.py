@@ -8,7 +8,8 @@ and the files named after them (``chipbench.spec``).  One process, on
 the machine it is started on:
 
 1. set-up (``setup_s``, from process start): draw the tables from
-   ``--seed``, pack them, upload every table to the device, build the
+   ``--seed`` and hold them as the configuration says (``data.load``:
+   by default packed, every table on the device), build the
    ``QueryServer``, and send each distinct query of the mix once, which
    loads or compiles every program the window runs;
 2. the window: one closed-loop client sends the mix's queries, each as
@@ -141,17 +142,9 @@ def measure(cell: spec.Cell, cfg: dict, mix: dict, seed: int,
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
     try:
-        tables = data.generate(cfg, seed)
-        log(f"generated seed={seed} s={time.perf_counter() - t0:.3f}")
-        db = data.to_program(tables, cfg["sf"])
-        log(f"packed fact_bytes={db.lineorder.nbytes} "
-            f"s={time.perf_counter() - t0:.3f}")
-        resident = data.make_resident(db)
-        log(f"resident bytes={resident} s={time.perf_counter() - t0:.3f}")
+        tables, placed = data.load(cfg, seed)
+        server = QueryServer(**placed)
         plans = engine.ssb_queries()
-        # the whole packed fact table is one morsel
-        server = QueryServer(db, mode="auto",
-                             morsel_bytes=db.lineorder.nbytes)
         strategy = mix["strategy"]
 
         def one(name: str) -> dict:
@@ -191,7 +184,7 @@ def measure(cell: spec.Cell, cfg: dict, mix: dict, seed: int,
         f"setup_s={setup_s:.3f} compiles_in_window={in_window} "
         f"compiles_in_setup={len(compiles) - in_window}")
     mem = memory_peak(devices)
-    del server, db
+    del server, placed
     gc.collect()
 
     if traced:

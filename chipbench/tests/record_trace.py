@@ -32,9 +32,8 @@ def main(out: str) -> int:
     from repro.sql.server import QueryServer
 
     cfg = shrink(spec.config("ssb_sf10"), ROWS)
-    db = data.to_program(data.generate(cfg, seed=7), cfg["sf"])
-    data.make_resident(db)
-    server = QueryServer(db, mode="auto", morsel_bytes=db.lineorder.nbytes)
+    _, placed = data.load(cfg, seed=7)
+    server = QueryServer(**placed)
     plans = engine.ssb_queries()
 
     def one(name: str) -> None:

@@ -3,7 +3,7 @@ import json
 
 import pytest
 
-from chipbench import loadgen, reference, spec
+from chipbench import data, loadgen, reference, spec
 
 BENCH = spec.benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -64,3 +64,22 @@ def test_split_metric_reads_with_its_base_reader():
         "device_idle_share.py"
     assert spec.metric_path("device_idle_share").name == \
         "device_idle_share.py"
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_config_key_is_one_the_loader_knows(entry):
+    cfg = json.loads((spec.ROOT / entry["file"]).read_text())
+    assert set(cfg) <= data.KEYS
+    assert data.placement(cfg) == (cfg.get("layout", "packed"),
+                                   cfg.get("fact_morsel_bytes"))
+
+
+@pytest.mark.parametrize("key, value", [
+    ("layout", "int32"), ("layout", "Plain"), ("layout", None),
+    ("fact_morsel_bytes", 0), ("fact_morsel_bytes", -64),
+    ("fact_morsel_bytes", 1.5), ("fact_morsel_bytes", "67108864"),
+    ("fact_morsel_bytes", True), ("fact_morsel_bytes", None),
+    ("fact_morsel_byte", 1 << 26)])
+def test_a_bad_placement_raises_naming_its_key(tiny_cfg, key, value):
+    with pytest.raises(ValueError, match=key):
+        data.load({**tiny_cfg, key: value}, seed=1)
